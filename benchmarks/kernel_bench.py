@@ -1,8 +1,9 @@
 """Kernel micro-benchmarks.
 
-On this CPU container Pallas kernels execute in interpret mode (Python), so
-wall-times are NOT TPU-representative; what we report per kernel is
-  * the jnp-reference wall time (compiled on CPU — a real baseline),
+These time the jnp references, not the Pallas kernels, on whatever backend
+JAX picked (the kernels run compiled only on a TPU: ``chip_smoke.py``); what
+we report per kernel is
+  * the jnp-reference wall time (a host-side baseline, not a chip number),
   * the analytic FLOPs and HBM bytes of the kernel's workload,
   * arithmetic intensity + the projected TPU-v5e roofline time
     max(flops/197e12, bytes/819e9) for the default production tile shapes —

@@ -393,6 +393,9 @@ def bench_roofline():
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     print("name,us_per_call,derived")
     if which in ("all", "solver"):

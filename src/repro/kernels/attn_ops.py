@@ -1,8 +1,7 @@
-"""Jit'd public wrapper for flash attention (padding + interpret fallback)."""
+"""Jit'd public wrapper for flash attention (padding to block multiples)."""
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention_pallas
@@ -11,10 +10,11 @@ from repro.kernels.attn_ref import flash_attention_ref
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, bq: int = 128, bk: int = 128,
-                    interpret: bool | None = None):
-    """q (B,H,S,d), k/v (B,Kv,S,d). Pads seq to block multiples."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+                    interpret: bool = False):
+    """q (B,H,S,d), k/v (B,Kv,S,d). Pads seq to block multiples.
+
+    ``interpret=True`` runs the kernel in the Pallas interpreter (the CPU
+    tests); otherwise it is compiled for the TPU."""
     B, H, Sq, d = q.shape
     Skv = k.shape[2]
     bq_ = min(bq, max(8, Sq))
@@ -25,10 +25,9 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     kp = jnp.pad(k, ((0, 0), (0, 0), (0, Skp - Skv), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, Skp - Skv), (0, 0)))
     if Skp > Skv and not causal:
-        # padded kv must be masked; causal masks them iff Sqp==Skp alignment —
-        # handle by masking keys beyond Skv via a window-free causal trick:
-        # simplest correct route: fall back to reference for ragged non-causal
-        return flash_attention_ref(q, k, v, causal=causal, window=window, softcap=softcap)
+        # the kernel masks padded keys only through the causal frontier
+        raise ValueError(f"non-causal flash_attention needs kv length {Skv} to "
+                         f"be a multiple of the kv block {bk_}")
     o = flash_attention_pallas(qp, kp, vp, causal=causal, window=window,
                                softcap=softcap, bq=bq_, bk=bk_, interpret=interpret)
     return o[:, :, :Sq, :]

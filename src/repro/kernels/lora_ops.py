@@ -1,14 +1,11 @@
 """Jit'd public wrapper for the fused LoRA matmul.
 
 Handles arbitrary leading batch dims, non-aligned shapes (zero padding to
-block multiples), dtype promotion, and the CPU fallback (interpret mode when
-no TPU is attached — used by tests; on TPU the compiled kernel runs)."""
+block multiples) and dtype promotion.  ``interpret=True`` runs the kernel in
+the Pallas interpreter (the CPU tests); otherwise it is compiled for the TPU."""
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.lora_matmul import lora_matmul_pallas
@@ -20,10 +17,8 @@ def _round_up(x: int, m: int) -> int:
 
 
 def lora_matmul(x, w, a, b, *, scale: float = 1.0, bm: int = 128, bn: int = 128,
-                bk: int = 512, interpret: bool | None = None):
+                bk: int = 512, interpret: bool = False):
     """y = x·W + scale·(x·A)·B with x (..., K), w (K, N), a (K, r), b (r, N)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     lead = x.shape[:-1]
     K = x.shape[-1]
     N = w.shape[1]

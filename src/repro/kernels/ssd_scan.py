@@ -12,8 +12,11 @@ This is the TPU adaptation of the Mamba-2 GPU kernel: instead of warp-level
 scans, the inter-chunk recurrence is carried in VMEM between grid steps (the
 TPU grid is sequential), and all O(Q²)/O(Q·N·P) work is shaped for the MXU.
 
-Grid = (B, H, S/Q); chunks innermost.  x (B,S,H,P), dt (B,S,H) pre-scaled
-outside, A (H,), Bm/Cm (B,S,N) shared across heads (groups = 1).
+Grid = (B, H, S/Q); chunks innermost.  x (B,H,S,P) head-major so that every
+block's last two dims are (Q, P)/(Q, N), dt (B,H,S) pre-scaled outside (laid
+out as one (1, Q) row per chunk), A (H,) in SMEM, Bm/Cm (B,S,N) shared across
+heads (groups = 1).  The in-chunk cumulative sums are triangular matmuls, so
+row and column forms of the decay come out of the MXU without a transpose.
 """
 
 from __future__ import annotations
@@ -25,6 +28,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_HI = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))  # contract the last dims: a · bᵀ
+
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *, Q: int):
     ic = pl.program_id(2)
@@ -33,44 +39,51 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *, Q: int):
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)  # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)  # (Q,)
-    A = a_ref[0].astype(jnp.float32)  # scalar (negative)
-    Bm = b_ref[0].astype(jnp.float32)  # (Q, N)
-    Cm = c_ref[0].astype(jnp.float32)  # (Q, N)
+    x = x_ref[...].astype(jnp.float32)  # (Q, P)
+    dt = dt_ref[...].astype(jnp.float32)  # (1, Q)
+    A = a_ref[pl.program_id(1)]  # scalar (negative)
+    Bm = b_ref[...].astype(jnp.float32)  # (Q, N)
+    Cm = c_ref[...].astype(jnp.float32)  # (Q, N)
 
-    la = dt * A  # (Q,) log decay per step
-    cs = jnp.cumsum(la)  # (Q,)
-    xw = x * dt[:, None]  # dt-weighted input
+    row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    mask = row >= col
+    tril = mask.astype(jnp.float32)
+    eye = (row == col).astype(jnp.float32)
+
+    la = dt * A  # (1, Q) log decay per step
+    cs_col = jax.lax.dot_general(tril, la, _NT, precision=_HI,
+                                 preferred_element_type=jnp.float32)  # (Q, 1)
+    cs_row = jax.lax.dot_general(la, tril, _NT, precision=_HI,
+                                 preferred_element_type=jnp.float32)  # (1, Q)
+    dt_col = jax.lax.dot_general(eye, dt, _NT, precision=_HI,
+                                 preferred_element_type=jnp.float32)  # (Q, 1)
+    xw = x * dt_col  # dt-weighted input
 
     # intra-chunk: scores[q, s] = (C_q·B_s) · exp(cs_q - cs_s) for s <= q
-    seg = cs[:, None] - cs[None, :]
-    mask = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    L = jnp.where(mask, jnp.exp(seg), 0.0)
-    scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
+    L = jnp.where(mask, jnp.exp(cs_col - cs_row), 0.0)
+    scores = jax.lax.dot_general(Cm, Bm, _NT,
                                  preferred_element_type=jnp.float32) * L
     y = jax.lax.dot(scores, xw, preferred_element_type=jnp.float32)  # (Q, P)
 
     # inter-chunk: contribution of the carried state
-    decay_in = jnp.exp(cs)[:, None]  # decay from chunk start to step q
+    decay_in = jnp.exp(cs_col)  # (Q, 1) decay from chunk start to step q
     y += jax.lax.dot(Cm * decay_in, state_ref[...],
                      preferred_element_type=jnp.float32)  # (Q,N)x(N,P)
 
     # state update: h = exp(sum la)·h + Bᵀ·(decay_to_end ⊙ xw)
-    total = cs[-1]
-    decay_out = jnp.exp(total - cs)[:, None]  # (Q, 1)
-    state_ref[...] = jnp.exp(total) * state_ref[...] + jax.lax.dot_general(
-        Bm, xw * decay_out, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)  # (N, Q)x(Q, P) -> (N, P)
+    total = jnp.sum(la)
+    decay_out = jnp.exp(total - cs_col)  # (Q, 1)
+    state_ref[...] = jnp.exp(total) * state_ref[...] + jax.lax.dot(
+        Bm.T, xw * decay_out, preferred_element_type=jnp.float32)  # (N, P)
 
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    y_ref[...] = y.astype(y_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan_pallas(x, dt, A, Bm, Cm, *, chunk: int = 256, interpret: bool = False):
-    """x: (B,S,H,P); dt: (B,S,H); A: (H,); Bm/Cm: (B,S,N) -> y (B,S,H,P)."""
-    B, S, H, P = x.shape
+    """x: (B,H,S,P); dt: (B,H,S); A: (H,); Bm/Cm: (B,S,N) -> y (B,H,S,P)."""
+    B, H, S, P = x.shape
     N = Bm.shape[-1]
     Q = min(chunk, S)
     assert S % Q == 0
@@ -81,14 +94,14 @@ def ssd_scan_pallas(x, dt, A, Bm, Cm, *, chunk: int = 256, interpret: bool = Fal
         functools.partial(_kernel, Q=Q),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, Q, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, Q, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
-            pl.BlockSpec((1, Q, N), lambda b, h, c: (b, c, 0)),
-            pl.BlockSpec((1, Q, N), lambda b, h, c: (b, c, 0)),
+            pl.BlockSpec((None, None, Q, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((None, None, None, 1, Q), lambda b, h, c: (b, h, c, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, Q, N), lambda b, h, c: (b, c, 0)),
+            pl.BlockSpec((None, Q, N), lambda b, h, c: (b, c, 0)),
         ],
-        out_specs=pl.BlockSpec((1, Q, 1, P), lambda b, h, c: (b, c, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, P), x.dtype),
+        out_specs=pl.BlockSpec((None, None, Q, P), lambda b, h, c: (b, h, c, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, Bm, Cm)
+    )(x, dt.reshape(B, H, nc, 1, Q), A.astype(jnp.float32), Bm, Cm)

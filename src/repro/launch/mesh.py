@@ -3,48 +3,31 @@
 A function (never a module-level constant) so importing this module never
 touches jax device state — the dry-run sets XLA_FLAGS *before* first jax
 init, and smoke tests must keep seeing 1 device.
-
-``axis_types`` landed in jax.sharding after 0.4.37; every constructor here
-feature-detects it so the same code runs on both the pinned container
-toolchain and newer jax.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AbstractMesh, AxisType
 
 
-def _axis_types_kw(n_axes: int) -> dict:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:  # jax <= 0.4.37: implicit auto axes
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips ('data','model') per pod; 2 pods with a leading
     'pod' axis for the multi-pod dry-run."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
-
-
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
 def make_host_mesh():
     """Whatever devices exist, as a 1-D 'data' mesh (CPU tests/examples)."""
-    n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",), **_axis_types_kw(1))
+    return make_mesh((len(jax.devices()),), ("data",))
 
 
 def make_abstract_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Device-less mesh for spec math, across the AbstractMesh API change:
-    jax >= 0.5 takes ``(shape, axis_names)``; 0.4.x takes name/size pairs."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    """Device-less mesh for spec math."""
+    return AbstractMesh(shape, axes)

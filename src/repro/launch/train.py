@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.checkpoint import Checkpointer
 from repro.config import FedsLLMConfig, TrainConfig, get_arch, smoke_variant
 from repro.data.tokens import TokenStream
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models import transformer as T
 
@@ -181,6 +182,7 @@ def main():
                     help="per-client data distribution (repro.fl.workloads): "
                          "iid | quantity-skew | length-skew | dirichlet")
     args = ap.parse_args()
+    use_compile_cache()
     if args.fedsllm:
         train_fedsllm(args)
     else:

@@ -1,4 +1,8 @@
+import importlib.util
 import os
+import pathlib
+
+import pytest
 
 # Tests run against the single host CPU device (the dry-run, and ONLY the
 # dry-run, forces 512 placeholder devices).
@@ -17,3 +21,14 @@ except ImportError:
 import jax
 
 jax.config.update("jax_enable_x64", False)
+
+
+@pytest.fixture(scope="session")
+def chip_smoke():
+    """The repo-root ``chip_smoke.py`` as a module; importing it touches no
+    device, only ``main()`` does."""
+    path = pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

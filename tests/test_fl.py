@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _golden import GOLDEN_LOSSES, GOLDEN_ROUND_TIMES, GOLDEN_TOTAL_TIME
 
 from repro.api import (Experiment, get_local_algo, get_workload, local_algos,
                        workloads)
@@ -135,15 +136,8 @@ def test_local_iteration_count_consistent_with_delay_model():
 
 
 # ---------------------------------------------------------------------------
-# gd bit-compat golden (same capture as tests/test_topology.py: smoke
-# fedsllm-100m, K=6, EB, eta=0.5, cohort 4, 0.7-quantile deadline, 3 rounds)
+# gd bit-compat golden (tests/_golden.py, shared with tests/test_topology.py)
 # ---------------------------------------------------------------------------
-
-GOLDEN_DEADLINE = 110.61189496631023
-GOLDEN_LOSSES = (5.556713104248047, 5.560213088989258, 5.551358222961426)
-GOLDEN_ROUND_TIMES = (110.61189496631023, 110.61189496631023,
-                      104.78746742360255)
-GOLDEN_TOTAL_TIME = 326.01125735622304
 
 
 def test_gd_campaign_matches_pre_registry_golden(gd_run):

@@ -31,7 +31,7 @@ def test_lora_matmul_matches_ref(M, K, N, r, dtype, tol):
     w = jax.random.normal(ks[1], (K, N), dtype) * 0.05
     a = jax.random.normal(ks[2], (K, r), dtype) * 0.05
     b = jax.random.normal(ks[3], (r, N), dtype) * 0.05
-    y = lora_matmul(x, w, a, b, scale=2.0)
+    y = lora_matmul(x, w, a, b, scale=2.0, interpret=True)
     ref = lora_matmul_ref(x, w, a, b, scale=2.0)
     np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(ref, np.float32),
                                rtol=tol, atol=tol)
@@ -43,7 +43,7 @@ def test_lora_matmul_batched_leading_dims():
     w = jax.random.normal(ks[1], (64, 32), jnp.float32) * 0.1
     a = jax.random.normal(ks[2], (64, 4), jnp.float32) * 0.1
     b = jax.random.normal(ks[3], (4, 32), jnp.float32) * 0.1
-    y = lora_matmul(x, w, a, b)
+    y = lora_matmul(x, w, a, b, interpret=True)
     ref = lora_matmul_ref(x.reshape(16, 64), w, a, b).reshape(2, 8, 32)
     np.testing.assert_allclose(np.asarray(y), np.asarray(ref), rtol=1e-5, atol=1e-5)
 
@@ -55,7 +55,7 @@ def test_lora_matmul_zero_B_equals_base():
     w = jax.random.normal(ks[1], (128, 64), jnp.float32) * 0.1
     a = jax.random.normal(ks[2], (128, 8), jnp.float32)
     b = jnp.zeros((8, 64), jnp.float32)
-    y = lora_matmul(x, w, a, b, scale=4.0)
+    y = lora_matmul(x, w, a, b, scale=4.0, interpret=True)
     np.testing.assert_allclose(np.asarray(y), np.asarray(x @ w), rtol=1e-5, atol=1e-5)
 
 
@@ -79,7 +79,8 @@ def test_flash_attention_matches_ref(B, H, Kv, S, d, window, softcap, dtype, tol
     q = jax.random.normal(ks[0], (B, H, S, d), dtype)
     k = jax.random.normal(ks[1], (B, Kv, S, d), dtype)
     v = jax.random.normal(ks[2], (B, Kv, S, d), dtype)
-    o = flash_attention(q, k, v, window=window, softcap=softcap, bq=64, bk=64)
+    o = flash_attention(q, k, v, window=window, softcap=softcap, bq=64, bk=64,
+                        interpret=True)
     ref = flash_attention_ref(q, k, v, window=window, softcap=softcap)
     np.testing.assert_allclose(np.asarray(o, np.float32), np.asarray(ref, np.float32),
                                rtol=tol, atol=tol)
@@ -91,7 +92,7 @@ def test_flash_attention_rows_sum_to_one_property():
     q = jax.random.normal(jax.random.PRNGKey(0), (B, H, S, d))
     k = jax.random.normal(jax.random.PRNGKey(1), (B, H, S, d))
     v = jnp.ones((B, H, S, d))
-    o = flash_attention(q, k, v, bq=64, bk=64)
+    o = flash_attention(q, k, v, bq=64, bk=64, interpret=True)
     np.testing.assert_allclose(np.asarray(o), 1.0, rtol=1e-5)
 
 
@@ -116,7 +117,7 @@ def test_ssd_scan_matches_sequential_ref(B, S, H, P, N, chunk, dtype, rtol):
     A = -jnp.exp(jax.random.normal(ks[2], (H,), dtype) * 0.3)
     Bm = jax.random.normal(ks[3], (B, S, N), dtype) * 0.5
     Cm = jax.random.normal(ks[4], (B, S, N), dtype) * 0.5
-    y = ssd_scan(x, dt, A, Bm, Cm, chunk=chunk)
+    y = ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
     ref = ssd_scan_ref(x, dt, A, Bm, Cm)
     scale = float(jnp.max(jnp.abs(ref))) + 1e-6
     np.testing.assert_allclose(np.asarray(y) / scale, np.asarray(ref) / scale,
@@ -133,6 +134,14 @@ def test_ssd_decay_property():
     A = jnp.full((H,), -50.0)  # decay exp(-50) ≈ 0
     Bm = jax.random.normal(ks[1], (B, S, N))
     Cm = jax.random.normal(ks[2], (B, S, N))
-    y = ssd_scan(x, dt, A, Bm, Cm, chunk=16)
+    y = ssd_scan(x, dt, A, Bm, Cm, chunk=16, interpret=True)
     expected = jnp.einsum("bsn,bsn,bshp->bshp", Cm, Bm, x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(expected), rtol=1e-4, atol=1e-4)
+
+
+def test_flash_attention_ragged_non_causal_raises():
+    """Padded keys are masked only through the causal frontier, so a ragged
+    non-causal call is refused instead of answered by another code path."""
+    q = jnp.ones((1, 2, 96, 32))
+    with pytest.raises(ValueError, match="non-causal"):
+        flash_attention(q, q, q, causal=False, bq=64, bk=64, interpret=True)

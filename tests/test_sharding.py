@@ -11,8 +11,7 @@ from repro.launch.mesh import make_abstract_mesh, make_mesh
 
 @pytest.fixture(scope="module")
 def mesh():
-    # 1 real device but spec_for math only needs the mesh SHAPE semantics;
-    # make_abstract_mesh spans the AbstractMesh API change across jax versions
+    # 1 real device but spec_for math only needs the mesh SHAPE semantics
     return make_abstract_mesh((16, 16), ("data", "model"))
 
 

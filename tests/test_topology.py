@@ -9,6 +9,8 @@ import dataclasses
 import jax
 import numpy as np
 import pytest
+from _golden import (GOLDEN_DEADLINE, GOLDEN_LOSSES, GOLDEN_ROUND_TIMES,
+                     GOLDEN_TOTAL_TIME)
 
 from repro.api import Experiment, get_topology, topologies
 from repro.config import (FedsLLMConfig, LoRAConfig, RunConfig, SHAPES,
@@ -301,20 +303,13 @@ def test_edge_agg_campaign_single_trace_under_reattachment(run_cfg, stream):
 # star: bit-identical to the pre-topology engine
 # ---------------------------------------------------------------------------
 
-# Golden trajectory captured from the pre-topology engine (PR 3 HEAD):
-# smoke fedsllm-100m (lora rank 4 / alpha 8), K=6, EB, eta=0.5, cohort 4,
-# deadline = 0.7-quantile of the constructor timing, 3 resampled rounds.
-GOLDEN_DEADLINE = 110.61189496631023
-GOLDEN_LOSSES = (5.556713104248047, 5.560213088989258, 5.551358222961426)
-GOLDEN_ROUND_TIMES = (110.61189496631023, 110.61189496631023,
-                      104.78746742360255)
-GOLDEN_TOTAL_TIME = 326.01125735622304
+# Golden trajectory (tests/_golden.py, shared with tests/test_fl.py).
 
 
 def test_star_campaign_matches_pre_topology_golden(run_cfg, stream):
     """The default topology IS the legacy engine: simulator quantities
-    reproduce the pre-topology trajectory exactly, training losses to float
-    tolerance (the golden was captured before repro.net existed)."""
+    reproduce the pre-topology trajectory exactly (the times were captured
+    before repro.net existed), training losses to float tolerance."""
     exp = _fresh(run_cfg)
     assert exp.topology.name == "star" and exp.assign is None
     deadline = float(np.quantile(exp.timing.total, 0.7))
