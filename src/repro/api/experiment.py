@@ -381,6 +381,24 @@ class Experiment:
         client→edge membership rides along as a value-only argument, so the
         per-edge aggregation tracks re-attachment without retracing.
         """
+        args = self.round_args(batches, key=key, mask=mask,
+                               client_ids=client_ids, weight_scale=weight_scale,
+                               update_scale=update_scale)
+        if self.local_algo.stateful:
+            self.state, metrics, self.algo_state = self._round_fn(*args)
+        else:
+            self.state, metrics = self._round_fn(*args)
+        return RoundResult(self.state, metrics, self.timing)
+
+    def round_args(self, batches, key: Optional[jax.Array] = None,
+                   mask: Optional[jax.Array] = None,
+                   client_ids: Optional[np.ndarray] = None,
+                   weight_scale: Optional[np.ndarray] = None,
+                   update_scale: Optional[float] = None) -> tuple:
+        """The positional arguments :meth:`run_round` passes to ``round_fn``
+        for these ``batches`` (same keywords), from the current state:
+        ``round_fn.lower(*exp.round_args(b)).compile()`` is the program a
+        round runs.  ``batches`` may hold ``jax.ShapeDtypeStruct`` leaves."""
         C = jax.tree.leaves(batches)[0].shape[0]
         ids = (np.arange(C) if client_ids is None
                else np.asarray(client_ids))
@@ -397,17 +415,12 @@ class Experiment:
                 np.eye(M, dtype=np.float32)[np.asarray(self.assign)[ids]])
         scale = (None if update_scale is None
                  else jnp.asarray(update_scale, jnp.float32))
+        args = (self.state, batches, mask, key, weights, assign, scale)
         if self.local_algo.stateful:
             # cohort→population row map for the variates: value-only, so
             # elastic cohorts reuse the same trace
-            algo_ids = jnp.asarray(ids, jnp.int32)
-            self.state, metrics, self.algo_state = self._round_fn(
-                self.state, batches, mask, key, weights, assign, scale,
-                self.algo_state, algo_ids)
-        else:
-            self.state, metrics = self._round_fn(self.state, batches, mask,
-                                                 key, weights, assign, scale)
-        return RoundResult(self.state, metrics, self.timing)
+            args += (self.algo_state, jnp.asarray(ids, jnp.int32))
+        return args
 
     def run(self, num_rounds: Optional[int] = None, **kwargs) -> "CampaignResult":
         """Run a multi-round campaign (the ``repro.sim`` engine).

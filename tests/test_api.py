@@ -110,6 +110,20 @@ def test_build_round_fn_contract(run_cfg, batches):
     assert not hasattr(fedsllm, "make_round_fn")  # deprecation completed
 
 
+@pytest.mark.parametrize("local_algo", ["gd", "scaffold"])
+def test_round_args_are_what_run_round_passes(run_cfg, batches, local_algo):
+    """``round_fn(*round_args(b))`` is ``run_round(b)``, on one trace: the
+    program a caller lowers from ``round_args`` is the one rounds run."""
+    exp = Experiment.from_config(run_cfg, allocator="EB", local_algo=local_algo)
+    ids = np.arange(CLIENTS)[::-1]
+    out = exp.round_fn(*exp.round_args(batches, client_ids=ids))
+    res = exp.run_round(batches, client_ids=ids)
+    assert exp.trace_count == 1
+    for a, b in zip(jax.tree.leaves((out[0], out[2:])),
+                    jax.tree.leaves((res.state, exp.algo_state))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_weighted_aggregation_matters(run_cfg, batches):
     """Non-uniform D_k weights must change the aggregated update."""
     exp = Experiment.from_config(run_cfg, allocator="EB")

@@ -8,6 +8,7 @@ fixture: only the worker that runs this file loads the TPU library.
 """
 
 import os
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -16,9 +17,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.config import get_arch
-from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.lora_matmul import lora_matmul_pallas
-from repro.kernels.ssd_scan import ssd_scan_pallas
+from repro.kernels.attn_ops import flash_attention
+from repro.kernels.lora_ops import lora_matmul
+from repro.kernels.ssd_ops import ssd_scan
 
 
 @pytest.fixture(scope="module")
@@ -56,11 +57,8 @@ def _assert_kernel(compiled):
 def test_lora_matmul_compiles_at_fedsllm_100m_width(one_chip):
     cfg = get_arch("fedsllm-100m")
     M, D, F, r = 2048, cfg.d_model, cfg.d_ff, cfg.lora.rank
-    # the wrapper pads K=768 to the 512-deep block and r to 8's
-    Dp, rp = 1024, r
-    args = [_spec(s, jnp.bfloat16, one_chip)
-            for s in ((M, Dp), (Dp, F), (Dp, rp), (rp, F))]
-    fn = lambda x, w, a, b: lora_matmul_pallas(x, w, a, b, scale=cfg.lora.scale)  # noqa: E731
+    args = [_spec(s, jnp.bfloat16, one_chip) for s in ((M, D), (D, F), (D, r), (r, F))]
+    fn = partial(lora_matmul, scale=cfg.lora.scale, interpret=False)
     _assert_kernel(jax.jit(fn).lower(*args).compile())
 
 
@@ -69,19 +67,20 @@ def test_flash_attention_compiles_at_fedsllm_100m_width(one_chip):
     S = 1024
     q = _spec((1, cfg.num_heads, S, cfg.head_dim), jnp.bfloat16, one_chip)
     kv = _spec((1, cfg.num_kv_heads, S, cfg.head_dim), jnp.bfloat16, one_chip)
-    _assert_kernel(jax.jit(flash_attention_pallas).lower(q, kv, kv).compile())
+    fn = partial(flash_attention, causal=True, interpret=False)
+    _assert_kernel(jax.jit(fn).lower(q, kv, kv).compile())
 
 
 def test_ssd_scan_compiles_at_mamba2_130m_width(one_chip):
     cfg = get_arch("mamba2-130m")
     H = cfg.ssm_expand * cfg.d_model // cfg.ssm_head_dim
     B, S, P, N = 1, 1024, cfg.ssm_head_dim, cfg.ssm_state
-    args = (_spec((B, H, S, P), jnp.float32, one_chip),
-            _spec((B, H, S), jnp.float32, one_chip),
+    args = (_spec((B, S, H, P), jnp.float32, one_chip),
+            _spec((B, S, H), jnp.float32, one_chip),
             _spec((H,), jnp.float32, one_chip),
             _spec((B, S, N), jnp.float32, one_chip),
             _spec((B, S, N), jnp.float32, one_chip))
-    fn = lambda *a: ssd_scan_pallas(*a, chunk=cfg.ssm_chunk)  # noqa: E731
+    fn = partial(ssd_scan, chunk=cfg.ssm_chunk, interpret=False)
     _assert_kernel(jax.jit(fn).lower(*args).compile())
 
 
@@ -93,7 +92,8 @@ def test_smoke_round_program_fits_one_v5e(one_chip, chip_smoke):
         (chip_smoke.CLIENTS, chip_smoke.BATCH, chip_smoke.SEQ), jnp.int32)
     batches = {"tokens": tok, "labels": tok,
                "mask": jax.ShapeDtypeStruct(tok.shape, jnp.float32)}
+    # the arguments run_round passes, moved onto the described chip
     args = jax.tree.map(lambda a: _spec(np.shape(a), a.dtype, one_chip),
-                        chip_smoke.round_args(exp, batches))
+                        exp.round_args(batches))
     mem = chip_smoke.compiled_bytes(exp.round_fn.lower(*args).compile())
     assert 0 < mem["total"] <= chip_smoke.HBM_BUDGET_BYTES, mem
