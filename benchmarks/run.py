@@ -19,7 +19,6 @@ run machine-readably to ``results/BENCH_round.json`` (name →
                   O(cohort); also writes results/BENCH_scale.json
   kernels         lora / attention / ssd micro-benches (median of
                   KERNEL_REPEATS calls; gated with per-entry thresholds)
-  roofline        summary over dry-run artifacts (if present)
 """
 
 from __future__ import annotations
@@ -375,23 +374,6 @@ def bench_compression():
     emit("compression_delay", us, f"topk10pct_delay_gain={gain:.2f}%")
 
 
-def bench_roofline():
-    try:
-        from benchmarks.roofline import load_table
-
-        rows = load_table()
-        if not rows:
-            emit("roofline", 0.0, "no_dryrun_artifacts_yet")
-            return
-        worst = min(rows, key=lambda r: r["roofline_fraction"])
-        best = max(rows, key=lambda r: r["roofline_fraction"])
-        emit("roofline_cells", float(len(rows)),
-             f"best={best['arch']}/{best['shape']}={100*best['roofline_fraction']:.1f}%_"
-             f"worst={worst['arch']}/{worst['shape']}={100*worst['roofline_fraction']:.1f}%")
-    except Exception as e:  # artifacts optional for the harness
-        emit("roofline", 0.0, f"unavailable:{type(e).__name__}")
-
-
 def main() -> None:
     from repro.launch.compile_cache import use_compile_cache
 
@@ -418,8 +400,6 @@ def main() -> None:
         bench_compression()
     if which in ("all", "fig2"):
         bench_fig2()
-    if which in ("all", "roofline"):
-        bench_roofline()
     write_json()
 
 

@@ -171,13 +171,13 @@ def build_round_fn(cfg: ModelConfig, fcfg: FedsLLMConfig, cut: int, eta: float,
     def _round(state: FedsLLMState, batches, mask, key, weights, assign,
                update_scale, algo_state, algo_ids):
         K = jax.tree.leaves(batches)[0].shape[0]
-        if two_tier and assign is not None:
-            # hierarchical fed-server role: per-edge then cross-edge
-            def agg(tree):
-                return federated.hier_aggregate(aggregate, tree, assign,
-                                                weights=weights, mask=mask)
-        else:
-            def agg(tree):
+
+        def agg(tree):
+            with jax.named_scope("fedsllm.aggregate"):
+                if two_tier and assign is not None:
+                    # hierarchical fed-server role: per-edge then cross-edge
+                    return federated.hier_aggregate(aggregate, tree, assign,
+                                                    weights=weights, mask=mask)
                 return aggregate(tree, weights=weights, mask=mask)
         # 2. round-start gradients per client (h=0)
         loss0, g0 = jax.vmap(lambda b: client_grads(state.base, state.lora_c,
@@ -231,8 +231,9 @@ def build_round_fn(cfg: ModelConfig, fcfg: FedsLLMConfig, cut: int, eta: float,
         # α = 1 (the paper's rule) unless an async schedule passes its
         # staleness mixing rate
         alpha = 1.0 if update_scale is None else update_scale
-        new_lc = federated.apply_update(state.lora_c, agg(h_c), alpha)
-        new_ls = federated.apply_update(state.lora_s, agg(h_s), alpha)
+        with jax.named_scope("fedsllm.aggregate"):
+            new_lc = federated.apply_update(state.lora_c, agg(h_c), alpha)
+            new_ls = federated.apply_update(state.lora_s, agg(h_s), alpha)
         metrics = {
             "loss_round_start": jnp.mean(loss0),
             "loss_local_final": jnp.mean(last_loss),

@@ -74,20 +74,22 @@ def init_lora(params, axes, cfg: ModelConfig, key=None, abstract: bool = False):
 
 
 def merge(params, lora_params, cfg: ModelConfig):
-    """W' = W + (α/r)·A@B at every targeted leaf; other leaves pass through."""
+    """W' = W + (α/r)·A@B at every targeted leaf; other leaves pass through.
+    Traced under the scope ``lora.merge``."""
     lcfg = cfg.lora or LoRAConfig()
     scale = lcfg.scale
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     merged = []
-    for path, leaf in flat:
-        pstr = jax.tree_util.keystr(path)
-        if pstr in lora_params:
-            ab = lora_params[pstr]
-            delta = jnp.einsum("...ir,...ro->...io", ab["A"].astype(jnp.float32),
-                               ab["B"].astype(jnp.float32)) * scale
-            merged.append((leaf.astype(jnp.float32) + delta).astype(leaf.dtype))
-        else:
-            merged.append(leaf)
+    with jax.named_scope("lora.merge"):
+        for path, leaf in flat:
+            pstr = jax.tree_util.keystr(path)
+            if pstr in lora_params:
+                ab = lora_params[pstr]
+                delta = jnp.einsum("...ir,...ro->...io", ab["A"].astype(jnp.float32),
+                                   ab["B"].astype(jnp.float32)) * scale
+                merged.append((leaf.astype(jnp.float32) + delta).astype(leaf.dtype))
+            else:
+                merged.append(leaf)
     return jax.tree_util.tree_unflatten(treedef, merged)
 
 

@@ -196,6 +196,22 @@ def _attend_chunked_q(q, k, v, *, causal: bool, window: int, softcap: float,
     return jnp.moveaxis(outs, 0, 1).reshape(B, S, H * hd)
 
 
+def _attend(q, k, v, cfg: ModelConfig, *, causal: bool, window: int, q_chunk: int,
+            unroll: bool):
+    """The attention core over in-flight k/v (banded, query-chunked or full),
+    traced under the scope ``model.attention``; the projections are the
+    caller's."""
+    S = q.shape[1]
+    softcap = cfg.attn_logit_softcap
+    with jax.named_scope("model.attention"):
+        if causal and window and window > 0 and S % window == 0 and S > window:
+            return _attend_banded(q, k, v, window=window, softcap=softcap)
+        if q_chunk and S > q_chunk:
+            return _attend_chunked_q(q, k, v, causal=causal, window=window,
+                                     softcap=softcap, chunk=q_chunk, unroll=unroll)
+        return _attend_full(q, k, v, causal=causal, window=window, softcap=softcap)
+
+
 def attention(
     p,
     x: jax.Array,
@@ -256,8 +272,9 @@ def attention(
         ck = shard(ck, ("batch", "kv_seq", "kv_heads", None))
         cv = shard(cv, ("batch", "kv_seq", "kv_heads", None))
         new_cache = (ck, cv)
-        out = _decode_attend(q, ck, cv, cfg=cfg, window=window, cache_pos=cache_pos,
-                             kpos_abs=kpos_abs)
+        with jax.named_scope("model.attention"):
+            out = _decode_attend(q, ck, cv, cfg=cfg, window=window, cache_pos=cache_pos,
+                                 kpos_abs=kpos_abs)
     elif cache is not None:
         # ---- prefill: attend with in-flight k/v, write the cache ----------
         ck, cv = cache
@@ -273,25 +290,11 @@ def attention(
         ck = shard(ck, ("batch", "kv_seq", "kv_heads", None))
         cv = shard(cv, ("batch", "kv_seq", "kv_heads", None))
         new_cache = (ck, cv)
-        if causal and window and window > 0 and S % window == 0 and S > window:
-            out = _attend_banded(q, k, v, window=window, softcap=cfg.attn_logit_softcap)
-        elif q_chunk and S > q_chunk:
-            out = _attend_chunked_q(q, k, v, causal=causal, window=window,
-                                    softcap=cfg.attn_logit_softcap, chunk=q_chunk,
-                                    unroll=unroll_chunks)
-        else:
-            out = _attend_full(q, k, v, causal=causal, window=window,
-                               softcap=cfg.attn_logit_softcap)
+        out = _attend(q, k, v, cfg, causal=causal, window=window, q_chunk=q_chunk,
+                      unroll=unroll_chunks)
     else:
-        if causal and window and window > 0 and S % window == 0 and S > window:
-            out = _attend_banded(q, k, v, window=window, softcap=cfg.attn_logit_softcap)
-        elif q_chunk and S > q_chunk:
-            out = _attend_chunked_q(q, k, v, causal=causal, window=window,
-                                    softcap=cfg.attn_logit_softcap, chunk=q_chunk,
-                                    unroll=unroll_chunks)
-        else:
-            out = _attend_full(q, k, v, causal=causal, window=window,
-                               softcap=cfg.attn_logit_softcap)
+        out = _attend(q, k, v, cfg, causal=causal, window=window, q_chunk=q_chunk,
+                      unroll=unroll_chunks)
     out = shard(out, ("batch", "seq", "heads"))
     y = out @ p["wo"].astype(x.dtype)
     if "bo" in p:

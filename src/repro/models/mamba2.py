@@ -81,7 +81,13 @@ def _segsum(log_a):
 
 def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, initial_state=None):
     """Chunked SSD. x: (B,S,H,P); dt: (B,S,H); A: (H,) (negative);
-    Bm/Cm: (B,S,N).  Returns (y (B,S,H,P), final_state (B,H,P,N))."""
+    Bm/Cm: (B,S,N).  Returns (y (B,S,H,P), final_state (B,H,P,N)).
+    Traced under the scope ``model.ssd``."""
+    with jax.named_scope("model.ssd"):
+        return _ssd_chunked(x, dt, A, Bm, Cm, chunk, initial_state)
+
+
+def _ssd_chunked(x, dt, A, Bm, Cm, chunk: int, initial_state=None):
     B, S, H, P = x.shape
     N = Bm.shape[-1]
     Q = min(chunk, S)
@@ -92,7 +98,7 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, initial_state=None):
         dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
         Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0)))
         Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0)))
-        y, h = ssd_chunked(x, dt, A, Bm, Cm, Q, initial_state)
+        y, h = _ssd_chunked(x, dt, A, Bm, Cm, Q, initial_state)
         return y[:, :S], h
     nc = S // Q
 

@@ -25,6 +25,7 @@ exactly the rounds an uninterrupted one would have run.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Callable, Optional, TYPE_CHECKING
 
 import jax
@@ -125,6 +126,7 @@ def stream_batcher(stream, num_clients: int) -> Callable[[int, np.ndarray], Any]
     return fn
 
 
+@partial(jax.profiler.annotate_function, name="fedsllm.campaign")
 def run_campaign(exp: "Experiment", num_rounds: Optional[int] = None, *,
                  stream=None, batches=None,
                  batches_fn: Optional[Callable[[int, np.ndarray], Any]] = None,
@@ -193,6 +195,12 @@ def run_campaign(exp: "Experiment", num_rounds: Optional[int] = None, *,
     rides through the round function and the mask/staleness weights select
     the arrivals (``repro.des.schedules``).  Per-event timing records land
     on ``RoundRecord.events``.
+
+    Profiling: the call is the host span ``fedsllm.campaign`` and each round
+    ``fedsllm.round`` (arguments ``round``, ``cohort``, ``I_loc``), holding
+    ``fedsllm.round_state``, ``fedsllm.plan``, ``fedsllm.batches``,
+    ``fedsllm.dispatch`` and ``fedsllm.read`` (``jax.profiler`` spans,
+    recorded only while a profile is being taken).
     """
     fcfg = exp.fcfg
     K = fcfg.num_clients
@@ -330,61 +338,73 @@ def run_campaign(exp: "Experiment", num_rounds: Optional[int] = None, *,
         realloc_search=search)
     records: list[RoundRecord] = []
     for r in range(start, target):
-        # (a) per-round scenario: channel evolution + re-attachment +
-        # allocation + timing (``events.round_state`` — under
-        # reallocate=True problems (16)/(17) re-solve jointly on this
-        # round's realisation, per edge cell under a hierarchical topology,
-        # and the solved η* is adopted quantized onto the η-bucket grid so
-        # the Lemma 1/2 schedule tracks the channel without recompiling)
-        if resample_channel:
-            # timeline planners (async) already priced every round while
-            # simulating run durations — reuse instead of re-solving
-            priced = getattr(planner, "pricing", {}).get(r)
-            net, assign, alloc, _, timing = (
-                priced if priced is not None else events.round_state(
-                    exp, campaign_seed, r, base_alloc=base_alloc,
-                    reallocate=reallocate, realloc_search=search))
-            exp.net, exp.assign, exp.alloc = net, assign, alloc
-            if reallocate:
-                base_alloc = alloc
-                exp.set_eta(alloc.eta)
-            exp.timing = timing
+        # host spans on the profiler's clock: the round (its index, cohort
+        # and I_loc as arguments) and, inside it, each step's host time
+        with jax.profiler.TraceAnnotation("fedsllm.round", round=r) as round_span:
+            # (a) per-round scenario: channel evolution + re-attachment +
+            # allocation + timing (``events.round_state`` — under
+            # reallocate=True problems (16)/(17) re-solve jointly on this
+            # round's realisation, per edge cell under a hierarchical topology,
+            # and the solved η* is adopted quantized onto the η-bucket grid so
+            # the Lemma 1/2 schedule tracks the channel without recompiling)
+            if resample_channel:
+                with jax.profiler.TraceAnnotation("fedsllm.round_state"):
+                    # timeline planners (async) already priced every round while
+                    # simulating run durations — reuse instead of re-solving
+                    priced = getattr(planner, "pricing", {}).get(r)
+                    net, assign, alloc, _, timing = (
+                        priced if priced is not None else events.round_state(
+                            exp, campaign_seed, r, base_alloc=base_alloc,
+                            reallocate=reallocate, realloc_search=search))
+                    exp.net, exp.assign, exp.alloc = net, assign, alloc
+                    if reallocate:
+                        base_alloc = alloc
+                        exp.set_eta(alloc.eta)
+                    exp.timing = timing
 
-        # (b) elastic cohort + (c) schedule: completion events → straggler
-        # mask, staleness weights and the round's simulated wall-clock
-        ids = (np.arange(cohort) if fixed_cohort is not None
-               else events.cohort_ids(r, K, cohort, seed=campaign_seed))
-        plan = planner.round_plan(r, ids)
-        if plan.client_ids is not None:  # async family: full population
-            ids = plan.client_ids
-        # population compaction: gather the arrivals + in-flight window of
-        # a K-sized async plan onto the fixed (C,) window (identity under
-        # ``exact`` and for sync-family plans)
-        plan, ids = pop.compact_plan(plan, ids, r)
-        mask_np = plan.mask
-        mask = None if mask_np is None else jnp.asarray(mask_np)
-        round_time = plan.round_time
+            # (b) elastic cohort + (c) schedule: completion events → straggler
+            # mask, staleness weights and the round's simulated wall-clock
+            with jax.profiler.TraceAnnotation("fedsllm.plan"):
+                ids = (np.arange(cohort) if fixed_cohort is not None
+                       else events.cohort_ids(r, K, cohort, seed=campaign_seed))
+                plan = planner.round_plan(r, ids)
+                if plan.client_ids is not None:  # async family: full population
+                    ids = plan.client_ids
+                # population compaction: gather the arrivals + in-flight window of
+                # a K-sized async plan onto the fixed (C,) window (identity under
+                # ``exact`` and for sync-family plans)
+                plan, ids = pop.compact_plan(plan, ids, r)
+                mask_np = plan.mask
+                mask = None if mask_np is None else jnp.asarray(mask_np)
+                round_time = plan.round_time
+            round_span.set_metadata(
+                cohort=len(ids), I_loc=fedsllm.local_iteration_count(fcfg, exp.eta))
 
-        # (d) train the round through the ONE jitted round function
-        res = exp.run_round(pop.device_batch(batches_fn(r, ids)),
-                            mask=mask, client_ids=ids,
-                            weight_scale=plan.weight_scale,
-                            update_scale=plan.update_scale)
+            # (d) train the round through the ONE jitted round function
+            with jax.profiler.TraceAnnotation("fedsllm.batches"):
+                batches_r = pop.device_batch(batches_fn(r, ids))
+            with jax.profiler.TraceAnnotation("fedsllm.dispatch"):
+                res = exp.run_round(batches_r, mask=mask, client_ids=ids,
+                                    weight_scale=plan.weight_scale,
+                                    update_scale=plan.update_scale)
+            # the host waits here for the round's device work
+            with jax.profiler.TraceAnnotation("fedsllm.read"):
+                metrics = {k: float(v) for k, v in res.metrics.items()}
 
-        cumulative += round_time
-        rec = RoundRecord(
-            round=r, client_ids=np.asarray(ids), mask=mask_np,
-            metrics={k: float(v) for k, v in res.metrics.items()},
-            alloc=exp.alloc, timing=exp.timing,
-            round_time=round_time, cumulative_time=cumulative, eta=exp.eta,
-            events=plan.events, staleness=plan.staleness,
-            completion=plan.completion)
-        records.append(rec)
-        if on_round is not None:
-            on_round(rec)
+            cumulative += round_time
+            rec = RoundRecord(
+                round=r, client_ids=np.asarray(ids), mask=mask_np,
+                metrics=metrics,
+                alloc=exp.alloc, timing=exp.timing,
+                round_time=round_time, cumulative_time=cumulative, eta=exp.eta,
+                events=plan.events, staleness=plan.staleness,
+                completion=plan.completion)
+            records.append(rec)
+            if on_round is not None:
+                on_round(rec)
 
-        if ckpt is not None and checkpoint_every and (r + 1) % checkpoint_every == 0:
-            _save(ckpt, exp, r + 1, cumulative, campaign_seed, reallocate)
+            if ckpt is not None and checkpoint_every and (r + 1) % checkpoint_every == 0:
+                _save(ckpt, exp, r + 1, cumulative, campaign_seed, reallocate)
 
     if ckpt is not None and target > start:
         saved_on_loop = checkpoint_every and target % checkpoint_every == 0
