@@ -6,11 +6,15 @@ tree at the targeted projection leaves with {"A": (..., d_in, r),
 "B": (..., r, d_out)} factor pairs (leading stacked-layer / expert dims are
 preserved, so one declaration covers dense, scanned and MoE weights).
 
-Two application modes:
-  * ``merge``      — W' = W + (α/r)·A@B, used by the training path (autodiff
-                     through the merge yields exact dA/dB); cheap under remat.
-  * fused kernel   — y = x·W + (α/r)·(x·A)·B without materialising W', in
-                     ``repro/kernels/lora_matmul.py`` (the TPU hot path).
+Training applies adapters unmerged: ``attach`` wraps each targeted leaf W
+with its pair as ``layers.Adapted``, and every projection of the models
+computes y = x·W + (α/r)·(x·A)·B through ``layers.project``.  W stays the
+frozen leaf, shared by a vmapped cohort, and autodiff never forms a
+W-sized gradient: dA, dB and dx come from r-wide products.
+
+``merge`` (W' = W + (α/r)·A@B, rounded to W's dtype) is for export, where
+one adapter set serves many forward-only calls, and is the merged-graph
+oracle that ``split.monolithic_value_and_grad`` checks the split step against.
 """
 
 from __future__ import annotations
@@ -21,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import LoRAConfig, ModelConfig
+from repro.models.layers import Adapted
 from repro.parallel import ParamLeaf
 
 
@@ -73,6 +78,18 @@ def init_lora(params, axes, cfg: ModelConfig, key=None, abstract: bool = False):
     return out_vals, out_axes
 
 
+def attach(params, lora_params, cfg: ModelConfig):
+    """Every targeted leaf W becomes ``Adapted(W, A, B, α/r)``, applied
+    unmerged by ``layers.project``; other leaves pass through."""
+    scale = (cfg.lora or LoRAConfig()).scale
+
+    def one(path, leaf):
+        ab = lora_params.get(jax.tree_util.keystr(path))
+        return leaf if ab is None else Adapted(leaf, ab["A"], ab["B"], scale)
+
+    return jax.tree_util.tree_map_with_path(one, params)
+
+
 def merge(params, lora_params, cfg: ModelConfig):
     """W' = W + (α/r)·A@B at every targeted leaf; other leaves pass through.
     Traced under the scope ``lora.merge``."""
@@ -118,15 +135,16 @@ def lora_param_count(cfg: ModelConfig) -> int:
 
 def split_client_server(lora_params, cut_group: int):
     """Partition adapters at a scanned-group boundary: leaves under 'groups'
-    keyed by stacked-layer dim are sliced; embed-side leaves go to the client,
-    head/final-side to the server (paper: client holds the first A-fraction).
+    keyed by stacked-layer dim are sliced; embed-side and encoder leaves go
+    to the client, which runs the embedding and the encoder (encdec); head/
+    final-side to the server (paper: client holds the first A-fraction).
     """
     client, server = {}, {}
     for pstr, ab in lora_params.items():
-        if "groups" in pstr:
+        if pstr.startswith("['groups']"):
             client[pstr] = jax.tree.map(lambda x: x[:cut_group], ab)
             server[pstr] = jax.tree.map(lambda x: x[cut_group:], ab)
-        elif "embed" in pstr:
+        elif "embed" in pstr or pstr.startswith("['enc_groups']"):
             client[pstr] = ab
         else:
             server[pstr] = ab

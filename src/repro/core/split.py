@@ -49,10 +49,10 @@ def client_forward(client_base, lora_c, batch, cfg: ModelConfig, *, remat=False)
     ``transpose(jvp(fedsllm.client))``), which the benchmark's trace
     reduction reads by name."""
     with jax.named_scope("fedsllm.client"):
-        merged = lora_lib.merge(client_base, lora_c, cfg)
-        enc_out = T._run_encoder(merged, batch, cfg) if cfg.family == "encdec" else None
-        x, positions = T._embed_inputs(merged, batch, cfg)
-        x, _, _ = T._scan_groups(merged, x, cfg, positions=positions, enc_out=enc_out,
+        adapted = lora_lib.attach(client_base, lora_c, cfg)
+        enc_out = T._run_encoder(adapted, batch, cfg) if cfg.family == "encdec" else None
+        x, positions = T._embed_inputs(adapted, batch, cfg)
+        x, _, _ = T._scan_groups(adapted, x, cfg, positions=positions, enc_out=enc_out,
                                  remat=remat, include_tail=False)
         return x, enc_out
 
@@ -62,13 +62,13 @@ def server_forward_loss(server_base, lora_s, acts, batch, cfg: ModelConfig, *,
     """Remaining groups + tail + head + CE loss on the main server, traced
     under the scope ``fedsllm.server``."""
     with jax.named_scope("fedsllm.server"):
-        merged = lora_lib.merge(server_base, lora_s, cfg)
+        adapted = lora_lib.attach(server_base, lora_s, cfg)
         S = acts.shape[1]
         positions = jnp.arange(S)[None, :]
-        x, _, aux = T._scan_groups(merged, acts, cfg, positions=positions, enc_out=enc_out,
+        x, _, aux = T._scan_groups(adapted, acts, cfg, positions=positions, enc_out=enc_out,
                                    remat=remat, include_tail=True)
-        x = L.apply_norm(merged["final_norm"], x, cfg)
-        loss = L.fused_cross_entropy(merged["embed"], x, batch["labels"], cfg,
+        x = L.apply_norm(adapted["final_norm"], x, cfg)
+        loss = L.fused_cross_entropy(adapted["embed"], x, batch["labels"], cfg,
                                      mask=batch.get("mask"))
         return loss + 0.01 * aux
 
@@ -125,20 +125,17 @@ def split_value_and_grad(params, lora_c, lora_s, batch, cfg: ModelConfig, cut: i
 
 
 def monolithic_value_and_grad(params, lora_c, lora_s, batch, cfg: ModelConfig, cut: int):
-    """End-to-end autodiff reference — must equal split_value_and_grad."""
+    """End-to-end autodiff reference — must equal split_value_and_grad.
+
+    One graph through the merged weights W + (α/r)·A@B (``lora.merge``) and
+    the same client and server math with no adapters attached: independent
+    of the unmerged application that the split step uses."""
 
     def loss_fn(lc, ls):
-        full = lora_lib.join_client_server(lc, ls)
-        merged = lora_lib.merge(params, full, cfg)
-        loss, _ = T.loss_fn(merged, batch, cfg)
-        # note: T.loss_fn adds 0.01*aux internally; replicate server path
-        return loss
+        merged = lora_lib.merge(params, lora_lib.join_client_server(lc, ls), cfg)
+        parts = slice_base(merged, cut)
+        acts, enc_out = client_forward(parts.client_base, {}, batch, cfg)
+        return server_forward_loss(parts.server_base, {}, acts, batch, cfg, enc_out=enc_out)
 
-    # simpler exact reference: run the same two-phase math in one graph
-    def loss2(lc, ls):
-        parts = slice_base(params, cut)
-        acts, enc_out = client_forward(parts.client_base, lc, batch, cfg)
-        return server_forward_loss(parts.server_base, ls, acts, batch, cfg, enc_out=enc_out)
-
-    (loss), (dc, ds) = jax.value_and_grad(loss2, argnums=(0, 1))(lora_c, lora_s)
+    loss, (dc, ds) = jax.value_and_grad(loss_fn, argnums=(0, 1))(lora_c, lora_s)
     return loss, dc, ds

@@ -20,6 +20,47 @@ from repro.config import ModelConfig
 from repro.parallel import make_param, shard
 
 # ---------------------------------------------------------------------------
+# Projections
+# ---------------------------------------------------------------------------
+
+
+@jax.tree_util.register_pytree_node_class
+class Adapted:
+    """A frozen projection weight ``w`` with its LoRA pair attached, applied
+    unmerged by ``project`` as x·w + scale·(x·a)·b.  ``a`` and ``b`` keep
+    ``w``'s leading (stacked-layer, expert) dims, so ``lax.scan`` and
+    ``shard_map`` slice all three together; ``scale`` is static."""
+
+    def __init__(self, w, a, b, scale: float):
+        self.w, self.a, self.b, self.scale = w, a, b, scale
+
+    def tree_flatten(self):
+        return (self.w, self.a, self.b), self.scale
+
+    @classmethod
+    def tree_unflatten(cls, scale, children):
+        return cls(*children, scale)
+
+
+def project(x, w, spec: Optional[str] = None):
+    """x times a projection weight: ``x @ w`` or, given an einsum ``spec``
+    (expert weights), ``einsum(spec, x, w)``, in x's dtype.  An ``Adapted``
+    weight adds its adapter unmerged: x·W accumulates in float32, the adapter
+    (traced under the scope ``lora.adapter``) is float32 from x·A on, and the
+    sum is rounded once.  Its gradient never forms a W-sized array."""
+    def dot(u, v, **kw):
+        return jnp.matmul(u, v, **kw) if spec is None else jnp.einsum(spec, u, v, **kw)
+
+    if not isinstance(w, Adapted):
+        return dot(x, w.astype(x.dtype))
+    y = dot(x, w.w.astype(x.dtype), preferred_element_type=jnp.float32)
+    with jax.named_scope("lora.adapter"):
+        xa = dot(x, w.a.astype(x.dtype), preferred_element_type=jnp.float32)
+        y = y + w.scale * dot(xa, w.b.astype(jnp.float32))
+    return y.astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
 # Norms
 # ---------------------------------------------------------------------------
 
@@ -233,9 +274,9 @@ def attention(
     """
     B, S, D = x.shape
     H, Kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = x @ p["wq"].astype(x.dtype)
-    k = x @ p["wk"].astype(x.dtype)
-    v = x @ p["wv"].astype(x.dtype)
+    q = project(x, p["wq"])
+    k = project(x, p["wk"])
+    v = project(x, p["wv"])
     if "bq" in p:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
     q = q.reshape(B, S, H, hd)
@@ -296,7 +337,7 @@ def attention(
         out = _attend(q, k, v, cfg, causal=causal, window=window, q_chunk=q_chunk,
                       unroll=unroll_chunks)
     out = shard(out, ("batch", "seq", "heads"))
-    y = out @ p["wo"].astype(x.dtype)
+    y = project(out, p["wo"])
     if "bo" in p:
         y = y + p["bo"]
     return y, new_cache
@@ -366,16 +407,16 @@ def init_mlp(key, cfg: ModelConfig, abstract=False):
 def apply_mlp(p, x, cfg: ModelConfig):
     act = cfg.mlp_activation
     if act == "swiglu":
-        h = jax.nn.silu(x @ p["w_gate"].astype(x.dtype)) * (x @ p["w_up"].astype(x.dtype))
+        h = jax.nn.silu(project(x, p["w_gate"])) * project(x, p["w_up"])
     elif act == "geglu":
-        h = jax.nn.gelu(x @ p["w_gate"].astype(x.dtype), approximate=True) * (x @ p["w_up"].astype(x.dtype))
+        h = jax.nn.gelu(project(x, p["w_gate"]), approximate=True) * project(x, p["w_up"])
     else:
-        h = x @ p["w_up"].astype(x.dtype)
+        h = project(x, p["w_up"])
         if "b_up" in p:
             h = h + p["b_up"]
         h = jax.nn.gelu(h, approximate=True)
     h = shard(h, ("batch", "seq", "mlp"))
-    y = h @ p["w_down"].astype(x.dtype)
+    y = project(h, p["w_down"])
     if "b_down" in p:
         y = y + p["b_down"]
     return y

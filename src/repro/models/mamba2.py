@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig
+from repro.models.layers import project
 from repro.parallel import make_param, shard
 
 
@@ -166,7 +167,7 @@ def apply_mamba(p, u, cfg: ModelConfig, cache=None):
     Returns (out (B,S,D), new_cache)."""
     B, S, D = u.shape
     d_inner, H, P, N, conv_ch = dims(cfg)
-    zxbcdt = u @ p["in_proj"].astype(u.dtype)
+    zxbcdt = project(u, p["in_proj"])
     z = zxbcdt[..., :d_inner]
     xBC = zxbcdt[..., d_inner : d_inner + conv_ch]
     dt_raw = zxbcdt[..., d_inner + conv_ch :]  # (B,S,H)
@@ -195,7 +196,7 @@ def apply_mamba(p, u, cfg: ModelConfig, cache=None):
     var = jnp.mean(gf * gf, axis=-1, keepdims=True)
     g = (gf * jax.lax.rsqrt(var + 1e-6) * p["norm_scale"].astype(jnp.float32)).astype(u.dtype)
 
-    out = g @ p["out_proj"].astype(u.dtype)
+    out = project(g, p["out_proj"])
     new_cache = (new_conv_state, new_state) if cache is not None else None
     return out, new_cache
 

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig
+from repro.models.layers import project
 from repro.parallel import make_param, shard
 from repro.parallel.sharding import active_context, spec_for
 
@@ -155,7 +156,7 @@ def apply_moe(p, x, cfg: ModelConfig):
         # local path (CPU tests / no mesh)
         dest, keep = _rank_and_dest(top_e, E, C, k)
         xe = _dispatch_local(x, dest, keep, E_local=E, C=C, k=k, e_offset=0)
-        ye = _expert_ffn(p, xe, x.dtype)
+        ye = _expert_ffn(p, xe)
         y = _combine_local(ye, dest, keep, w_flat, S=S, k=k, e_offset=0)
         return y, {"moe_aux_loss": aux_loss}
 
@@ -180,31 +181,29 @@ def apply_moe(p, x, cfg: ModelConfig):
         dest, keep = _rank_and_dest(top_e_l, E, C, k)
         xe = _dispatch_local(x_l, dest, keep, E_local=E_local, C=C, k=k,
                              e_offset=e_off)
-        ye = _expert_ffn({"w_gate": w_gate, "w_up": w_up, "w_down": w_down},
-                         xe, x_l.dtype)
+        ye = _expert_ffn({"w_gate": w_gate, "w_up": w_up, "w_down": w_down}, xe)
         y = _combine_local(ye, dest, keep, w_flat_l, S=S, k=k, e_offset=e_off)
         if maxes:
             y = jax.lax.psum(y, maxes)
         return y
 
-    # expert weights enter sharded over (experts->model); other dims gathered
+    # expert weights (or Adapted ones: w, a and b all lead with the expert
+    # dim) enter sharded over (experts->model); other dims gathered
     wspec = P(maxes if maxes else None)
     y = jax.shard_map(
         sharded_moe, mesh=mesh,
         in_specs=(P(bspec), P(bspec), P(bspec), wspec, wspec, wspec),
         out_specs=P(bspec),
         check_vma=False,
-    )(x, top_e, w_flat,
-      p["w_gate"].astype(x.dtype), p["w_up"].astype(x.dtype),
-      p["w_down"].astype(x.dtype))
+    )(x, top_e, w_flat, p["w_gate"], p["w_up"], p["w_down"])
     return y, {"moe_aux_loss": aux_loss}
 
 
-def _expert_ffn(p, xe, dtype):
+def _expert_ffn(p, xe):
     """(b, E_l, C, D) -> (b, E_l, C, D) SwiGLU expert FFN (local shapes)."""
-    h = jax.nn.silu(jnp.einsum("becd,edf->becf", xe, p["w_gate"].astype(dtype)))
-    h = h * jnp.einsum("becd,edf->becf", xe, p["w_up"].astype(dtype))
-    return jnp.einsum("becf,efd->becd", h, p["w_down"].astype(dtype))
+    h = jax.nn.silu(project(xe, p["w_gate"], "becd,edf->becf"))
+    h = h * project(xe, p["w_up"], "becd,edf->becf")
+    return project(h, p["w_down"], "becf,efd->becd")
 
 
 def _mesh_axes(B: int, mesh, rules):

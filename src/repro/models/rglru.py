@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig
+from repro.models.layers import project
 from repro.parallel import make_param, shard
 
 _C = 8.0  # RG-LRU decay sharpness constant (Griffin)
@@ -73,8 +74,8 @@ def apply_rglru_block(p, u, cfg: ModelConfig, cache=None):
     from repro.models.mamba2 import _causal_conv
 
     B, S, D = u.shape
-    rec = u @ p["w_rec_in"].astype(u.dtype)  # (B,S,W)
-    gate = jax.nn.gelu(u @ p["w_gate_in"].astype(u.dtype), approximate=True)
+    rec = project(u, p["w_rec_in"])  # (B,S,W)
+    gate = jax.nn.gelu(project(u, p["w_gate_in"]), approximate=True)
 
     conv_state = cache[0] if cache is not None else None
     rec, new_conv_state = _causal_conv(rec, p["conv_w"], p["conv_b"], conv_state)
@@ -91,7 +92,7 @@ def apply_rglru_block(p, u, cfg: ModelConfig, cache=None):
         new_h = y[:, -1]
 
     y = shard(y.astype(u.dtype), ("batch", "seq", "mlp"))
-    out = (y * gate) @ p["w_out"].astype(u.dtype)
+    out = project(y * gate, p["w_out"])
     new_cache = (new_conv_state, new_h) if cache is not None else None
     return out, new_cache
 

@@ -169,18 +169,18 @@ def _cross_attention(p, x, enc_out, cfg: ModelConfig, cached_kv):
     """Cross-attention: q from x, k/v from encoder output (or cache)."""
     B, S, D = x.shape
     H, Kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = (x @ p["wq"].astype(x.dtype)).reshape(B, S, H, hd)
+    q = L.project(x, p["wq"]).reshape(B, S, H, hd)
     if cached_kv is not None:
         k, v = cached_kv
         k = k.astype(x.dtype)
         v = v.astype(x.dtype)
         new_kv = cached_kv
     else:
-        k = (enc_out @ p["wk"].astype(x.dtype)).reshape(B, -1, Kv, hd)
-        v = (enc_out @ p["wv"].astype(x.dtype)).reshape(B, -1, Kv, hd)
+        k = L.project(enc_out, p["wk"]).reshape(B, -1, Kv, hd)
+        v = L.project(enc_out, p["wv"]).reshape(B, -1, Kv, hd)
         new_kv = (k, v)
     out = L._attend_full(q, k, v, causal=False, window=0, softcap=0.0)
-    return out @ p["wo"].astype(x.dtype), new_kv
+    return L.project(out, p["wo"]), new_kv
 
 
 # ---------------------------------------------------------------------------

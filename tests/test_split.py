@@ -9,6 +9,7 @@ import pytest
 from repro.config import LoRAConfig, get_arch, smoke_variant
 from repro.core import lora as lora_lib
 from repro.core import split
+from repro.models import layers as L
 from repro.models import transformer as T
 
 FAMILIES = ["fedsllm-100m", "olmoe-1b-7b", "mamba2-130m", "recurrentgemma-9b",
@@ -68,3 +69,45 @@ def test_smashed_bytes_scale_with_cut_position():
     cfg, params, lc, ls, batch = setup("fedsllm-100m", cut=1)
     _, _, _, info1 = split.split_value_and_grad(params, lc, ls, batch, cfg, 1)
     assert info1["smashed_bytes"] == info1["grad_bytes"]
+
+
+@pytest.mark.parametrize("arch", FAMILIES)
+def test_unmerged_adapters_match_merged_weights(arch):
+    """The split step's client and server functions apply adapters unmerged;
+    fed ``lora.merge``d weights and no adapters they give the same loss and
+    adapter gradients."""
+    cfg, params, lc, ls, batch = setup(arch)
+    parts = split.slice_base(params, 1)
+
+    def unmerged(lc, ls):
+        acts, enc_out = split.client_forward(parts.client_base, lc, batch, cfg)
+        return split.server_forward_loss(parts.server_base, ls, acts, batch, cfg,
+                                         enc_out=enc_out)
+
+    def merged(lc, ls):
+        acts, enc_out = split.client_forward(
+            lora_lib.merge(parts.client_base, lc, cfg), {}, batch, cfg)
+        return split.server_forward_loss(lora_lib.merge(parts.server_base, ls, cfg), {},
+                                         acts, batch, cfg, enc_out=enc_out)
+
+    loss_u, grads_u = jax.value_and_grad(unmerged, argnums=(0, 1))(lc, ls)
+    loss_m, grads_m = jax.value_and_grad(merged, argnums=(0, 1))(lc, ls)
+    np.testing.assert_allclose(float(loss_u), float(loss_m), rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(grads_u), jax.tree.leaves(grads_m)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("spec,w_shape", [(None, (64, 48)),
+                                          ("becd,edf->becf", (3, 64, 48))])
+def test_project_plain_weight_is_unchanged(spec, w_shape):
+    """Serving and every caller without adapters see the projection they had:
+    a plain weight gives ``x @ w.astype(x.dtype)`` (or its einsum) bit for bit."""
+    kx, kw = jax.random.split(jax.random.PRNGKey(4))
+    x_shape = (2, 16, 64) if spec is None else (2, 3, 5, 64)
+    x = jax.random.normal(kx, x_shape, jnp.float32).astype(jnp.bfloat16)
+    w = jax.random.normal(kw, w_shape, jnp.float32)
+    want = x @ w.astype(x.dtype) if spec is None else jnp.einsum(spec, x, w.astype(x.dtype))
+    got = L.project(x, w, spec)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))

@@ -13,11 +13,12 @@ import pytest
 from repro.api import Experiment
 from repro.config import FedsLLMConfig, LoRAConfig, RunConfig, SHAPES, get_arch, smoke_variant
 from repro.core import fedsllm
+from repro.core import lora as lora_lib
 from repro.data.tokens import TokenStream, client_batches
 
 ARCH = {"dense": "fedsllm-100m", "ssm": "mamba2-130m"}
 SPLIT_SCOPES = ["fedsllm.client", "transpose(jvp(fedsllm.client))", "fedsllm.server",
-                "lora.merge", "fedsllm.aggregate"]
+                "lora.adapter", "fedsllm.aggregate"]
 MODEL_SCOPE = {"dense": "model.attention", "ssm": "model.ssd"}
 SCOPE_CASES = ([(f, s) for f in ARCH for s in SPLIT_SCOPES]
                + [(f, MODEL_SCOPE[f]) for f in ARCH])
@@ -33,20 +34,35 @@ def _experiment(family: str) -> Experiment:
 
 
 def _stream(exp: Experiment) -> TokenStream:
-    return TokenStream(2, 32, exp.cfg.vocab_size, seed=0)
+    # 3 rows of 24 tokens: neither 24, 48 (query heads per kv head x seq)
+    # nor 72 (tokens a client) is a projection width of the smoke models,
+    # so a dot whose trailing dims are a weight's (d_in, d_out) is weight-sized
+    return TokenStream(3, 24, exp.cfg.vocab_size, seed=0)
 
 
 @pytest.fixture(scope="module")
-def op_names():
-    """family -> the op_name metadata of the compiled round function."""
+def compiled_round():
+    """family -> (Experiment, HLO text of its compiled round function)."""
     cache = {}
 
     def get(family):
         if family not in cache:
             exp = _experiment(family)
             batches = client_batches(_stream(exp), 0, COHORT)
-            text = exp.round_fn.lower(*exp.round_args(batches)).compile().as_text()
-            cache[family] = set(re.findall(r'op_name="([^"]*)"', text))
+            cache[family] = exp, exp.round_fn.lower(*exp.round_args(batches)).compile().as_text()
+        return cache[family]
+
+    return get
+
+
+@pytest.fixture(scope="module")
+def op_names(compiled_round):
+    """family -> the op_name metadata of the compiled round function."""
+    cache = {}
+
+    def get(family):
+        if family not in cache:
+            cache[family] = set(re.findall(r'op_name="([^"]*)"', compiled_round(family)[1]))
         return cache[family]
 
     return get
@@ -62,6 +78,22 @@ def _in_path(scope: str, op_name: str) -> bool:
 def test_scope_in_compiled_round(op_names, family, scope):
     names = op_names(family)
     assert any(_in_path(scope, n) for n in names), f"{scope!r} in no op_name of {family}"
+
+
+@pytest.mark.parametrize("family", list(ARCH))
+def test_round_never_forms_a_weight_sized_array(compiled_round, op_names, family):
+    """Adapters are applied unmerged: no dot of the round (merge, or the
+    frozen weight's gradient) yields a targeted weight's (d_in, d_out)."""
+    exp, text = compiled_round(family)
+    lcfg = exp.cfg.lora
+    weights = {tuple(leaf.shape[-2:]) for path, leaf in
+               jax.tree_util.tree_flatten_with_path(exp.state.base)[0]
+               if lora_lib.is_target(path, leaf, lcfg)}
+    dots = [tuple(int(d) for d in dims.split(",") if d)
+            for dims in re.findall(r"= \w+\[([\d,]*)\]\S* dot\(", text)]
+    assert dots
+    assert not [d for d in dots if d[-2:] in weights]
+    assert not any(_in_path("lora.merge", n) for n in op_names(family))
 
 
 @pytest.mark.parametrize("family", list(ARCH))
