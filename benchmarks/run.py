@@ -93,7 +93,7 @@ def bench_split_step():
     cfg = smoke_variant(get_arch("fedsllm-100m")).replace(lora=LoRAConfig(rank=4))
     params, axes = T.init_params(cfg, key=jax.random.PRNGKey(0))
     lora, _ = lora_lib.init_lora(params, axes, cfg, key=jax.random.PRNGKey(1))
-    lc, ls = lora_lib.split_client_server(lora, 1)
+    lc, ls = lora_lib.split_client_server(lora, 1, cfg)
     toks = jax.random.randint(jax.random.PRNGKey(2), (4, 64), 0, cfg.vocab_size)
     batch = {"tokens": toks, "labels": toks, "mask": jnp.ones((4, 64), jnp.float32)}
     fn = jax.jit(lambda lc, ls: split.split_value_and_grad(params, lc, ls, batch, cfg, 1)[0])

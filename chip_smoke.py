@@ -100,12 +100,13 @@ def mean_loss(state, batches, *, cfg, cut):
 
     from repro.core import split
 
-    parts = split.slice_base(state.base, cut)
+    parts = split.slice_base(state.base, cut, cfg)
 
     def one(batch):
-        acts, enc_out = split.client_forward(parts.client_base, state.lora_c, batch, cfg)
-        return split.server_forward_loss(parts.server_base, state.lora_s, acts, batch,
-                                         cfg, enc_out=enc_out)
+        acts, enc_out, aux = split.client_forward(parts.client_base, state.lora_c, batch, cfg)
+        loss, _ = split.server_forward_loss(parts.server_base, state.lora_s, acts, batch,
+                                            cfg, enc_out=enc_out)
+        return loss + split.AUX_WEIGHT * aux["moe_aux_loss"]
 
     return jnp.mean(jax.vmap(one)(batches))
 

@@ -85,7 +85,9 @@ class Experiment:
         if cfg.lora is None:
             cfg = cfg.replace(lora=LoRAConfig(rank=lora_rank))
         self.cfg = cfg
-        self.cut = (max(1, int(round(fcfg.split_ratio_min * cfg.num_groups)))
+        # the cut counts layers of the scanned stack; by default the client
+        # holds the first A_min share of its groups
+        self.cut = (cfg.group_size * max(1, int(round(fcfg.split_ratio_min * cfg.num_groups)))
                     if cut is None else cut)
 
         # --- strategy lookups (fail fast, with the known names) -------------
@@ -260,22 +262,33 @@ class Experiment:
                                          **self._round_fn_kw)
 
             # trace-counting wrapper: bumps only when jit (re)traces, so
-            # campaigns can assert they never recompile across rounds
+            # campaigns can assert they never recompile across rounds.  The
+            # jitted program returns the state without its frozen base (a
+            # jitted output is a new buffer: returning the base would copy
+            # it every round), and the call puts the base back
             if self.local_algo.stateful:
                 def _counted_round_fn(state, batches, mask, key, weights,
                                       assign=None, update_scale=None,
                                       algo_state=None, algo_ids=None):
                     self._traces += 1
-                    return raw(state, batches, mask, key, weights, assign,
-                               update_scale, algo_state, algo_ids)
+                    out = raw(state, batches, mask, key, weights, assign,
+                              update_scale, algo_state, algo_ids)
+                    return (out[0]._replace(base=None),) + out[1:]
             else:
                 def _counted_round_fn(state, batches, mask, key, weights,
                                       assign=None, update_scale=None):
                     self._traces += 1
-                    return raw(state, batches, mask, key, weights, assign,
-                               update_scale)
+                    out = raw(state, batches, mask, key, weights, assign,
+                              update_scale)
+                    return (out[0]._replace(base=None),) + out[1:]
 
-            fn = jax.jit(_counted_round_fn)
+            jitted = jax.jit(_counted_round_fn)
+
+            def fn(state, *args):
+                out = jitted(state, *args)
+                return (out[0]._replace(base=state.base),) + out[1:]
+
+            fn.lower = jitted.lower
             self._round_fns[key] = fn
         return fn
 
@@ -331,7 +344,9 @@ class Experiment:
 
     @property
     def round_fn(self):
-        """The underlying jitted round function (for benchmarking/inspection)."""
+        """The round function: ``round_fn(*round_args(b))`` runs a round;
+        ``round_fn.lower(*round_args(b))`` lowers its jitted program (for
+        benchmarking/inspection), which returns no copy of the base."""
         return self._round_fn
 
     @property
@@ -468,7 +483,7 @@ class Experiment:
     def describe(self) -> str:
         from repro.core.lora import lora_param_count
 
-        return (f"Experiment[{self.cfg.name}] cut={self.cut}/{self.cfg.num_groups} "
+        return (f"Experiment[{self.cfg.name}] cut={self.cut}/{self.cfg.num_layers} "
                 f"lora={lora_param_count(self.cfg)/1e6:.2f}M "
                 f"agg={self.aggregator_name} alloc={self.allocator_name} "
                 f"codec={self.compressor_name} scenario={self.scenario.name} "

@@ -47,13 +47,15 @@ class ModelConfig:
       dense   — standard decoder-only transformer (GQA + RoPE)
       moe     — dense attention + top-k routed expert MLP
       ssm     — Mamba-2 (SSD) attention-free stack
+      ssm-moe — Granite-4.0-H: Mamba-2 and GQA mixers, each followed by a
+                routed-plus-shared expert FFN
       hybrid  — RecurrentGemma: RG-LRU recurrent blocks : local attention, 1:2
       encdec  — whisper-style encoder-decoder (frame-embedding frontend stub)
       vlm     — LLaVA-NeXT: vision patch-embedding stub + decoder LM backbone
     """
 
     name: str
-    family: str  # dense | moe | ssm | hybrid | encdec | vlm
+    family: str  # dense | moe | ssm | ssm-moe | hybrid | encdec | vlm
 
     num_layers: int
     d_model: int
@@ -70,6 +72,7 @@ class ModelConfig:
     attn_logit_softcap: float = 0.0  # gemma2: 50.0 (0 = off)
     final_logit_softcap: float = 0.0  # gemma2: 30.0 (0 = off)
     sliding_window: int = 0  # 0 = global attention
+    attention_multiplier: float = 0.0  # softmax scale of the scores (0 -> 1/sqrt(head_dim))
     # Per-layer-group pattern, tiled over depth. "G"=global attn, "L"=local
     # (sliding-window) attn, "R"=recurrent (RG-LRU), "M"=mamba2 SSD block.
     layer_pattern: str = "G"
@@ -83,11 +86,19 @@ class ModelConfig:
     tie_embeddings: bool = False
     embedding_multiplier: float = 1.0  # gemma family: sqrt(d_model)
     logit_scale: float = 1.0  # command-r: 0.0625
+    residual_multiplier: float = 1.0  # x + m·branch on every residual branch (granite: 0.22)
+    norm_eps: float = 1e-6
 
     # --- MoE ----------------------------------------------------------------
+    # An expert layer holds num_experts experts, [expert_offset, expert_offset
+    # + num_experts) of the router's router_experts (0 -> num_experts, all
+    # held): one chip's share of an expert-parallel layer.
     num_experts: int = 0
     num_experts_per_tok: int = 0
     moe_router_norm: bool = True  # normalise top-k router weights
+    router_experts: int = 0
+    expert_offset: int = 0
+    shared_d_ff: int = 0  # width of a shared SwiGLU expert beside the routed ones (0 = none)
 
     # --- SSM (mamba2 / SSD) -------------------------------------------------
     ssm_state: int = 0
@@ -95,6 +106,7 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_chunk: int = 256
     ssm_conv_width: int = 4
+    ssm_ffn: bool = False  # "M" layers end in the feed-forward block too (granite-4.0-h)
 
     # --- hybrid (RG-LRU) ----------------------------------------------------
     lru_width: int = 0  # 0 -> d_model
@@ -125,6 +137,11 @@ class ModelConfig:
         p = self.layer_pattern
         reps = -(-self.num_layers // len(p))
         return (p * reps)[: self.num_layers]
+
+    @property
+    def num_router_experts(self) -> int:
+        """Experts the router scores: the layer's whole expert count."""
+        return self.router_experts or self.num_experts
 
     @property
     def group_size(self) -> int:
@@ -314,7 +331,9 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
     )
     if cfg.num_experts:
         kw.update(num_experts=8, num_experts_per_tok=2)
-    if cfg.family == "ssm":
+    if cfg.shared_d_ff:
+        kw.update(shared_d_ff=96)
+    if cfg.ssm_state:
         kw.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=16)
     if cfg.family == "hybrid":
         kw.update(lru_width=64, sliding_window=32)

@@ -4,6 +4,7 @@ from repro.configs import (  # noqa: F401
     command_r_35b,
     fedsllm_paper,
     gemma2_9b,
+    granite_4_0_h_small,
     llava_next_mistral_7b,
     mamba2_130m,
     olmoe_1b_7b,

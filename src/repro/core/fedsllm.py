@@ -51,7 +51,7 @@ def init_state(cfg: ModelConfig, cut: int, key=None) -> tuple[FedsLLMState, Any]
     k1, k2 = jax.random.split(key)
     base, axes = T.init_params(cfg, key=k1)
     lora_full, lora_axes = lora_lib.init_lora(base, axes, cfg, key=k2)
-    lc, ls = lora_lib.split_client_server(lora_full, cut)
+    lc, ls = lora_lib.split_client_server(lora_full, cut, cfg)
     return FedsLLMState(base, lc, ls, jnp.zeros((), jnp.int32)), (axes, lora_axes)
 
 
@@ -132,10 +132,11 @@ def build_round_fn(cfg: ModelConfig, fcfg: FedsLLMConfig, cut: int, eta: float,
     algo = get_local_algo("gd" if local_algo is None else local_algo)
 
     def client_grads(base, lc, ls, batch):
-        loss, dc, ds, _ = split.split_value_and_grad(base, lc, ls, batch, cfg, cut,
-                                                     remat=remat,
-                                                     compressor=compressor)
-        return loss, (dc, ds)
+        """(loss, (dlora_c, dlora_s), the held experts' loads or None)."""
+        loss, dc, ds, info = split.split_value_and_grad(base, lc, ls, batch, cfg, cut,
+                                                        remat=remat,
+                                                        compressor=compressor)
+        return loss, (dc, ds), info.get("moe_loads")
 
     def one_client_round(base, lc0, ls0, gk0, gbar, batch, ctrl=None,
                          ctrl_bar=None):
@@ -151,7 +152,7 @@ def build_round_fn(cfg: ModelConfig, fcfg: FedsLLMConfig, cut: int, eta: float,
             hc, hs = h
             lc = jax.tree.map(jnp.add, lc0, hc)
             ls = jax.tree.map(jnp.add, ls0, hs)
-            loss, (dc, ds) = client_grads(base, lc, ls, batch)
+            loss, (dc, ds), _ = client_grads(base, lc, ls, batch)
             # ∇G = ∇F_k(Δw+h) − ∇F_k(Δw) + ξ∇F(Δw)
             gc = jax.tree.map(lambda a, b, c: a - b + xi * c, dc, gk0[0], gbar[0])
             gs = jax.tree.map(lambda a, b, c: a - b + xi * c, ds, gk0[1], gbar[1])
@@ -180,8 +181,8 @@ def build_round_fn(cfg: ModelConfig, fcfg: FedsLLMConfig, cut: int, eta: float,
                                                     weights=weights, mask=mask)
                 return aggregate(tree, weights=weights, mask=mask)
         # 2. round-start gradients per client (h=0)
-        loss0, g0 = jax.vmap(lambda b: client_grads(state.base, state.lora_c,
-                                                    state.lora_s, b))(batches)
+        loss0, g0, routed = jax.vmap(lambda b: client_grads(state.base, state.lora_c,
+                                                            state.lora_s, b))(batches)
         # ḡ = ∇F(Δw) — fed-server aggregation (paper: uplink s_c per client)
         gbar = (agg(g0[0]), agg(g0[1]))
 
@@ -241,6 +242,8 @@ def build_round_fn(cfg: ModelConfig, fcfg: FedsLLMConfig, cut: int, eta: float,
             # applies directly to the stacked (K, ...) updates
             "h_c_norm": lora_lib.delta_norm(h_c),
         }
+        if routed is not None:
+            metrics.update(routing_counters(routed, batches, cfg))
         new_state = FedsLLMState(state.base, new_lc, new_ls, state.round + 1)
         return new_state, metrics, new_algo_state
 
@@ -263,6 +266,21 @@ def build_round_fn(cfg: ModelConfig, fcfg: FedsLLMConfig, cut: int, eta: float,
 
     round_fn.local_algo = algo
     return round_fn
+
+
+def routing_counters(loads, batches, cfg: ModelConfig) -> dict:
+    """The round-start pass's routing over the cohort, from the held
+    experts' loads (K, layers, E_held): ``moe_held_share``, the share of the
+    routed (token, expert) pairs that landed on a held expert (E_held /
+    router experts under even routing), and ``moe_max_load``, the largest
+    held expert's load over the mean held load, in the layer where that is
+    largest (1 = even)."""
+    K, n_layers, _ = loads.shape
+    tokens = math.prod(jax.tree.leaves(batches)[0].shape[1:])
+    per_layer = jnp.sum(loads, axis=0)  # (layers, E_held)
+    mean = jnp.maximum(jnp.mean(per_layer, axis=-1), 1e-9)
+    return {"moe_held_share": jnp.sum(loads) / (K * n_layers * tokens * cfg.num_experts_per_tok),
+            "moe_max_load": jnp.max(jnp.max(per_layer, axis=-1) / mean)}
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,7 @@ oracle that ``split.monolithic_value_and_grad`` checks the split step against.
 
 from __future__ import annotations
 
+import re
 from typing import Any, Optional
 
 import jax
@@ -26,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.config import LoRAConfig, ModelConfig
 from repro.models.layers import Adapted
+from repro.models.transformer import layer_view
 from repro.parallel import ParamLeaf
 
 
@@ -133,17 +135,20 @@ def lora_param_count(cfg: ModelConfig) -> int:
     return total
 
 
-def split_client_server(lora_params, cut_group: int):
-    """Partition adapters at a scanned-group boundary: leaves under 'groups'
-    keyed by stacked-layer dim are sliced; embed-side and encoder leaves go
-    to the client, which runs the embedding and the encoder (encdec); head/
+def split_client_server(lora_params, cut: int, cfg: ModelConfig):
+    """Partition adapters at layer ``cut`` of the scanned stack, as
+    ``split.slice_base`` partitions the weights: leaves under 'groups' go
+    by ``transformer.layer_view``; embed-side and encoder leaves go to the
+    client, which runs the embedding and the encoder (encdec); head/
     final-side to the server (paper: client holds the first A-fraction).
     """
+    n = cfg.num_groups * cfg.group_size
     client, server = {}, {}
     for pstr, ab in lora_params.items():
-        if pstr.startswith("['groups']"):
-            client[pstr] = jax.tree.map(lambda x: x[:cut_group], ab)
-            server[pstr] = jax.tree.map(lambda x: x[cut_group:], ab)
+        sub = re.match(r"\['groups'\]\['(sub_\d+)'\]", pstr)
+        if sub:
+            client[pstr] = layer_view({sub[1]: ab}, cfg, 0, cut)[sub[1]]
+            server[pstr] = layer_view({sub[1]: ab}, cfg, cut, n)[sub[1]]
         elif "embed" in pstr or pstr.startswith("['enc_groups']"):
             client[pstr] = ab
         else:

@@ -9,6 +9,7 @@ single-step decode against a (possibly ring-buffer) KV cache.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, NamedTuple, Optional
 
@@ -42,13 +43,17 @@ class Adapted:
         return cls(*children, scale)
 
 
-def project(x, w, spec: Optional[str] = None):
-    """x times a projection weight: ``x @ w`` or, given an einsum ``spec``
-    (expert weights), ``einsum(spec, x, w)``, in x's dtype.  An ``Adapted``
-    weight adds its adapter unmerged: x·W accumulates in float32, the adapter
-    (traced under the scope ``lora.adapter``) is float32 from x·A on, and the
-    sum is rounded once.  Its gradient never forms a W-sized array."""
+def project(x, w, spec: Optional[str] = None, group_sizes=None):
+    """x times a projection weight: ``x @ w``, given an einsum ``spec``
+    ``einsum(spec, x, w)``, or given ``group_sizes`` the grouped product
+    ``grouped_dot`` (rows of x sorted by expert, w stacked over experts), in
+    x's dtype.  An ``Adapted`` weight adds its adapter unmerged: x·W
+    accumulates in float32, the adapter (traced under the scope
+    ``lora.adapter``) is float32 from x·A on, and the sum is rounded once.
+    Its gradient never forms a W-sized array."""
     def dot(u, v, **kw):
+        if group_sizes is not None:
+            return grouped_dot(u, v, group_sizes, kw.get("preferred_element_type"))
         return jnp.matmul(u, v, **kw) if spec is None else jnp.einsum(spec, u, v, **kw)
 
     if not isinstance(w, Adapted):
@@ -58,6 +63,74 @@ def project(x, w, spec: Optional[str] = None):
         xa = dot(x, w.a.astype(x.dtype), preferred_element_type=jnp.float32)
         y = y + w.scale * dot(xa, w.b.astype(jnp.float32))
     return y.astype(x.dtype)
+
+
+# Grouped products over experts.  ``lax.ragged_dot`` batches under ``vmap``
+# only when every operand is batched, and a cohort's frozen expert weights
+# are not: broadcasting them would copy every expert once per client.  So
+# the two products below carry their own batching rule, one ragged product
+# per batch element against the shared (or that element's) weights, and
+# ``grouped_dot``'s gradient is written with them.
+
+def _ragged_dot(x, w, gs, pet):
+    """(m, k) rows sorted by group × (g, k, n) -> (m, n); rows past the
+    last group are 0 (the TPU's grouped product leaves them unwritten, and
+    what the buffer held, NaN or inf among it, must not reach a sum)."""
+    y = jax.lax.ragged_dot(x, w, gs, preferred_element_type=pet)
+    return jnp.where(jnp.arange(x.shape[0])[:, None] < jnp.sum(gs), y, 0)
+
+
+_RAGGED_OUTER = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])), lhs_ragged_dimensions=[0],
+    rhs_group_dimensions=[])
+
+
+def _ragged_outer(x, ct, gs, pet):
+    """Per group, the rows' xᵀ·ct: (m, k), (m, n) -> (g, k, n); 0 for an
+    empty group (which, as above, may be left unwritten)."""
+    y = jax.lax.ragged_dot_general(x, ct, gs, _RAGGED_OUTER, preferred_element_type=pet)
+    return jnp.where((gs > 0)[:, None, None], y, 0)
+
+
+@functools.lru_cache(maxsize=None)
+def _per_element(fn, pet):
+    """``fn`` at output dtype ``pet``, batched as one call per batch element."""
+    def call(a, b, gs):
+        return fn(a, b, gs, pet)
+
+    f = jax.custom_batching.custom_vmap(call)
+
+    @f.def_vmap
+    def _rule(axis_size, in_batched, a, b, gs):
+        pick = [(lambda t, c: t[c]) if bat else (lambda t, c: t) for bat in in_batched]
+        return jnp.stack([f(pick[0](a, c), pick[1](b, c), pick[2](gs, c))
+                          for c in range(axis_size)]), True
+
+    return f
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def grouped_dot(x, w, group_sizes, preferred_element_type=None):
+    """Rows of ``x`` (m, k), sorted by group with ``group_sizes`` (g,) rows
+    each, times their group's ``w`` (g, k, n): (m, n), rows past the last
+    group 0.  Batches under ``vmap`` with or without a batched ``w``."""
+    pet = jnp.dtype(preferred_element_type or x.dtype)
+    return _per_element(_ragged_dot, pet)(x, w, group_sizes)
+
+
+def _grouped_fwd(x, w, group_sizes, preferred_element_type):
+    return grouped_dot(x, w, group_sizes, preferred_element_type), (x, w, group_sizes)
+
+
+def _grouped_bwd(preferred_element_type, res, ct):
+    x, w, gs = res
+    dx = _per_element(_ragged_dot, jnp.dtype(jnp.float32))(
+        ct.astype(w.dtype), jnp.swapaxes(w, -1, -2), gs)
+    dw = _per_element(_ragged_outer, jnp.dtype(jnp.float32))(x, ct.astype(x.dtype), gs)
+    return dx.astype(x.dtype), dw.astype(w.dtype), None
+
+
+grouped_dot.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -72,7 +145,8 @@ def init_norm(key, cfg: ModelConfig, dim: int, prefix=(), abstract=False):
     return p
 
 
-def apply_norm(p, x, cfg: ModelConfig, eps: float = 1e-6):
+def apply_norm(p, x, cfg: ModelConfig):
+    eps = cfg.norm_eps
     xf = x.astype(jnp.float32)
     if cfg.norm_type == "rmsnorm":
         var = jnp.mean(xf * xf, axis=-1, keepdims=True)
@@ -160,15 +234,24 @@ def _softcap(logits, cap: float):
     return logits
 
 
+def score_divisor(cfg: ModelConfig) -> float:
+    """What attention scores are divided by: 1/attention_multiplier, else
+    √head_dim."""
+    m = cfg.attention_multiplier
+    return 1.0 / m if m else math.sqrt(cfg.head_dim)
+
+
 def _attend_full(q, k, v, *, causal: bool, window: int, softcap: float,
-                 q_offset: jax.Array | int = 0, kv_offset: jax.Array | int = 0):
-    """Dense masked attention. q: (B,Sq,H,hd); k/v: (B,Skv,Kv,hd)."""
+                 q_offset: jax.Array | int = 0, kv_offset: jax.Array | int = 0,
+                 divisor: Optional[float] = None):
+    """Dense masked attention. q: (B,Sq,H,hd); k/v: (B,Skv,Kv,hd); scores
+    over ``divisor`` (default √hd)."""
     B, Sq, H, hd = q.shape
     Kv = k.shape[2]
     rep = H // Kv
     qh = q.reshape(B, Sq, Kv, rep, hd)
     logits = jnp.einsum("bqkrh,bskh->bkrqs", qh, k, preferred_element_type=jnp.float32)
-    logits = logits / math.sqrt(hd)
+    logits = logits / (divisor or math.sqrt(hd))
     logits = _softcap(logits, softcap)
     qpos = jnp.arange(Sq) + q_offset  # absolute positions
     kpos = jnp.arange(k.shape[1]) + kv_offset
@@ -183,7 +266,7 @@ def _attend_full(q, k, v, *, causal: bool, window: int, softcap: float,
     return out.reshape(B, Sq, H * hd)
 
 
-def _attend_banded(q, k, v, *, window: int, softcap: float):
+def _attend_banded(q, k, v, *, window: int, softcap: float, divisor: Optional[float] = None):
     """Block-banded sliding-window attention: exact for causal window ≤ block.
 
     Splits seq into blocks of ``window``; block i attends to blocks {i-1, i}.
@@ -203,7 +286,7 @@ def _attend_banded(q, k, v, *, window: int, softcap: float):
     k2 = jnp.concatenate([kprev, kb], axis=2)  # (B,nb,2w,Kv,hd)
     v2 = jnp.concatenate([vprev, vb], axis=2)
     logits = jnp.einsum("bnqkrh,bnskh->bnkrqs", qb, k2, preferred_element_type=jnp.float32)
-    logits = _softcap(logits / math.sqrt(hd), softcap)
+    logits = _softcap(logits / (divisor or math.sqrt(hd)), softcap)
     qpos = jnp.arange(window)[:, None]  # within-block index
     kpos = jnp.arange(2 * window)[None, :] - window  # relative to block start
     mask = (kpos <= qpos) & (kpos > qpos - window)
@@ -216,7 +299,7 @@ def _attend_banded(q, k, v, *, window: int, softcap: float):
 
 
 def _attend_chunked_q(q, k, v, *, causal: bool, window: int, softcap: float,
-                      chunk: int, unroll: bool = False):
+                      chunk: int, unroll: bool = False, divisor: Optional[float] = None):
     """Query-chunked attention (bounds logits memory to S·chunk per head).
 
     Used for long prefill.  The KV tensors stay whole (flash-style online
@@ -228,7 +311,7 @@ def _attend_chunked_q(q, k, v, *, causal: bool, window: int, softcap: float,
     def one(i):
         qs = jax.lax.dynamic_slice_in_dim(q, i * chunk, chunk, axis=1)
         return _attend_full(qs, k, v, causal=causal, window=window, softcap=softcap,
-                            q_offset=i * chunk, kv_offset=0)
+                            q_offset=i * chunk, kv_offset=0, divisor=divisor)
 
     if unroll:
         outs = [one(i) for i in range(nq)]
@@ -244,13 +327,16 @@ def _attend(q, k, v, cfg: ModelConfig, *, causal: bool, window: int, q_chunk: in
     caller's."""
     S = q.shape[1]
     softcap = cfg.attn_logit_softcap
+    div = score_divisor(cfg)
     with jax.named_scope("model.attention"):
         if causal and window and window > 0 and S % window == 0 and S > window:
-            return _attend_banded(q, k, v, window=window, softcap=softcap)
+            return _attend_banded(q, k, v, window=window, softcap=softcap, divisor=div)
         if q_chunk and S > q_chunk:
             return _attend_chunked_q(q, k, v, causal=causal, window=window,
-                                     softcap=softcap, chunk=q_chunk, unroll=unroll)
-        return _attend_full(q, k, v, causal=causal, window=window, softcap=softcap)
+                                     softcap=softcap, chunk=q_chunk, unroll=unroll,
+                                     divisor=div)
+        return _attend_full(q, k, v, causal=causal, window=window, softcap=softcap,
+                            divisor=div)
 
 
 def attention(
@@ -361,7 +447,7 @@ def _decode_attend(q, ck, cv, *, cfg: ModelConfig, window: int, cache_pos, kpos_
     rep = H // Kv
     qh = q.reshape(B, Sq, Kv, rep, hd)
     logits = jnp.einsum("bqkrh,bskh->bkrqs", qh, ck.astype(q.dtype),
-                        preferred_element_type=jnp.float32) / math.sqrt(hd)
+                        preferred_element_type=jnp.float32) / score_divisor(cfg)
     logits = _softcap(logits, cfg.attn_logit_softcap)
     if kpos_abs is not None:  # ring buffer
         valid = (kpos_abs >= 0) & (kpos_abs <= cache_pos)

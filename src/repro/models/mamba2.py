@@ -194,7 +194,7 @@ def apply_mamba(p, u, cfg: ModelConfig, cache=None):
     g = y * jax.nn.silu(z)
     gf = g.astype(jnp.float32)
     var = jnp.mean(gf * gf, axis=-1, keepdims=True)
-    g = (gf * jax.lax.rsqrt(var + 1e-6) * p["norm_scale"].astype(jnp.float32)).astype(u.dtype)
+    g = (gf * jax.lax.rsqrt(var + cfg.norm_eps) * p["norm_scale"].astype(jnp.float32)).astype(u.dtype)
 
     out = project(g, p["out_proj"])
     new_cache = (new_conv_state, new_state) if cache is not None else None

@@ -1,7 +1,23 @@
-"""Mixture-of-Experts FFN: top-k routing with static capacity, rank-based
-dispatch, expert-parallel over the ``model`` mesh axis.
+"""Mixture-of-Experts FFN: top-k routing over every expert of the layer,
+its held experts' part of the result, and an optional shared expert.
 
-Final dispatch design (perf iterations 1-4, EXPERIMENTS.md §Perf):
+An expert layer holds ``cfg.num_experts`` experts, [expert_offset,
+expert_offset + num_experts) of the router's ``cfg.num_router_experts``:
+one chip's share of an expert-parallel layer, or all of them.  The router
+scores all experts in float32 and keeps the top k; the gates are the softmax
+of the chosen logits (``moe_router_norm``).  Pairs routed to experts held
+elsewhere add nothing here; a shared expert, computed alike on every share,
+is added once.  Scopes: ``model.moe`` around the whole FFN, inside it
+``model.moe.router``, ``model.moe.experts`` (dispatch, grouped products,
+combine) and ``model.moe.shared``.
+
+Without a mesh (every training path here) dispatch is dropless: the
+(token, expert) pairs are sorted by held expert and each expert's rows go
+through one grouped product (``layers.grouped_dot``), however unevenly the
+clients' data routes them.
+
+Under a mesh the experts are sharded over the ``model`` axis with a static
+capacity (design of perf iterations 1-4, EXPERIMENTS.md §Perf):
 
   * routing/top-k on (B,S,E) logits under GSPMD (small);
   * rank-within-expert via **argsort** — every intermediate is a (b, S·k)
@@ -22,8 +38,10 @@ Final dispatch design (perf iterations 1-4, EXPERIMENTS.md §Perf):
     v3 shard_map(data) dispatch: 5.5s / 115s mem / 125s coll (E all-gathers)
     v4 this file               : see EXPERIMENTS.md §Perf
 
-Overflow beyond an expert's per-row capacity C = ceil(cf·S·k/E) is dropped
-(GShard/Switch semantics, cf = 1.25).
+There, overflow beyond an expert's per-row capacity C = ceil(cf·S·k/E) is
+dropped (GShard/Switch semantics, cf = 1.25): static per-shard buffers are
+what expert-parallel dispatch over the mesh needs.  Where nothing overflows
+the two paths agree (tests/test_moe.py).
 """
 
 from __future__ import annotations
@@ -35,7 +53,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig
-from repro.models.layers import project
+from repro.models.layers import apply_mlp, project
 from repro.parallel import make_param, shard
 from repro.parallel.sharding import active_context, spec_for
 
@@ -44,26 +62,40 @@ CAPACITY_FACTOR = 1.25
 
 def init_moe(key, cfg: ModelConfig, abstract=False):
     D, F, E = cfg.d_model, cfg.d_ff, cfg.num_experts
-    ks = jax.random.split(key, 4) if key is not None else [None] * 4
-    return {
-        "router": make_param(ks[0], (D, E), ("embed", None), "float32", abstract=abstract),
+    ks = jax.random.split(key, 5) if key is not None else [None] * 5
+    p = {
+        "router": make_param(ks[0], (D, cfg.num_router_experts), ("embed", None), "float32",
+                             abstract=abstract),
         "w_gate": make_param(ks[1], (E, D, F), ("experts", "expert_embed", "mlp"), cfg.param_dtype, abstract=abstract),
         "w_up": make_param(ks[2], (E, D, F), ("experts", "expert_embed", "mlp"), cfg.param_dtype, abstract=abstract),
         "w_down": make_param(ks[3], (E, F, D), ("experts", "mlp", "expert_embed"), cfg.param_dtype,
                              scale=0.02 / math.sqrt(2 * cfg.num_layers), abstract=abstract),
     }
+    if cfg.shared_d_ff:
+        p["shared"] = init_shared(ks[4], cfg, abstract=abstract)
+    return p
 
 
-def expert_capacity(seq_tokens: int, cfg: ModelConfig) -> int:
-    """Per-batch-row expert capacity."""
-    E, k = cfg.num_experts, cfg.num_experts_per_tok
+def init_shared(key, cfg: ModelConfig, abstract=False):
+    D, F = cfg.d_model, cfg.shared_d_ff
+    ks = jax.random.split(key, 3) if key is not None else [None] * 3
+    return {
+        "w_gate": make_param(ks[0], (D, F), ("embed", "mlp"), cfg.param_dtype, abstract=abstract),
+        "w_up": make_param(ks[1], (D, F), ("embed", "mlp"), cfg.param_dtype, abstract=abstract),
+        "w_down": make_param(ks[2], (F, D), ("mlp", "embed"), cfg.param_dtype,
+                             scale=0.02 / math.sqrt(2 * cfg.num_layers), abstract=abstract),
+    }
+
+
+def _capacity(seq_tokens: int, E: int, k: int) -> int:
+    """Per-batch-row expert capacity of the mesh path."""
     c = int(math.ceil(CAPACITY_FACTOR * seq_tokens * k / E))
     c = max(c, min(seq_tokens * k, 8))
     return ((c + 7) // 8) * 8
 
 
 # ---------------------------------------------------------------------------
-# Local (per-shard) dispatch / combine
+# Per-shard dispatch / combine of the mesh path
 # ---------------------------------------------------------------------------
 
 
@@ -126,40 +158,88 @@ def _combine_local(ye, dest, keep, w_flat, *, S: int, k: int, e_offset):
 
 
 def apply_moe(p, x, cfg: ModelConfig):
-    """x: (B, S, D) -> (B, S, D), plus aux losses dict."""
-    B, S, D = x.shape
-    E, k = cfg.num_experts, cfg.num_experts_per_tok
-    C = expert_capacity(S, cfg)
+    """x: (B, S, D) -> (B, S, D), plus aux: the load-balancing loss over the
+    whole router (``moe_aux_loss``) and the (token, expert) pairs each held
+    expert received (``moe_loads``, (1, E_held))."""
+    with jax.named_scope("model.moe"):
+        with jax.named_scope("model.moe.router"):
+            top_e, w_flat, aux_loss = _route(p["router"], x, cfg)
+            local = top_e - cfg.expert_offset  # held experts are 0..E_held-1
+            E = cfg.num_experts
+            held = (local >= 0) & (local < E)
+            loads = jnp.zeros((E,), jnp.float32).at[jnp.where(held, local, E)].add(
+                1.0, mode="drop")
+        with jax.named_scope("model.moe.experts"):
+            y = _held_experts(p, x, local, w_flat, cfg)
+        if "shared" in p:
+            with jax.named_scope("model.moe.shared"):
+                y = y + apply_mlp(p["shared"], x, cfg)
+    return y, {"moe_aux_loss": aux_loss, "moe_loads": loads[None]}
 
-    # --- routing (fp32 logits; softmax over the selected k — qwen3/mixtral
-    # norm_topk semantics) ----------------------------------------------------
-    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
+
+def _route(router, x, cfg: ModelConfig):
+    """(top-k expert ids (B, S, k), their gates (B, S·k) in x's dtype, the
+    Switch-style load-balancing loss), all over the router's every expert."""
+    B, S, _ = x.shape
+    E, k = cfg.num_router_experts, cfg.num_experts_per_tok
+    # fp32 logits; softmax over the selected k (qwen3/mixtral/granite
+    # norm_topk semantics)
+    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32), router.astype(jnp.float32))
     top_l, top_e = jax.lax.top_k(logits, k)  # (B, S, k)
     if cfg.moe_router_norm:
         top_w = jax.nn.softmax(top_l, axis=-1)
     else:
         top_w = jnp.take_along_axis(jax.nn.softmax(logits, axis=-1), top_e, axis=-1)
-
-    # --- load-balancing auxiliary loss (Switch-style, no (…,E) one-hots) -----
+    # load-balancing auxiliary loss (Switch-style, no (…,E) one-hots)
     me = jnp.mean(jax.nn.softmax(logits, axis=-1), axis=(0, 1))  # (E,)
     bidx_e = jnp.broadcast_to(jnp.arange(B)[:, None], (B, S * k))
     counts = jnp.zeros((B, E), jnp.float32).at[bidx_e, top_e.reshape(B, S * k)].add(1.0)
     ce = jnp.sum(counts, axis=0) / (B * S * k)
     aux_loss = E * jnp.sum(me * ce)
+    return top_e, top_w.reshape(B, S * k).astype(x.dtype), aux_loss
 
-    w_flat = top_w.reshape(B, S * k).astype(x.dtype)
 
+def _held_experts(p, x, local, w_flat, cfg: ModelConfig):
+    """The held experts' part of the FFN for every token: Σ gate·expert(x)
+    over the token's pairs routed to a held expert (ids ``local`` in
+    [0, E_held); others add nothing)."""
+    B, S, D = x.shape
+    E, k = cfg.num_experts, cfg.num_experts_per_tok
     mesh, rules = active_context()
     baxes, maxes = _mesh_axes(B, mesh, rules)
     if mesh is None or (baxes is None and maxes is None):
-        # local path (CPU tests / no mesh)
-        dest, keep = _rank_and_dest(top_e, E, C, k)
-        xe = _dispatch_local(x, dest, keep, E_local=E, C=C, k=k, e_offset=0)
-        ye = _expert_ffn(p, xe)
-        y = _combine_local(ye, dest, keep, w_flat, S=S, k=k, e_offset=0)
-        return y, {"moe_aux_loss": aux_loss}
+        # the dropless buffers hold T·min(k, E) rows, most of them empty
+        # under even routing: recomputed in the backward pass rather than
+        # kept, which costs a few gathers and the held pairs' products
+        return jax.checkpoint(_dropless, static_argnums=(4, 5))(p, x, local, w_flat, E, k)
+    return _sharded_capacity(p, x, local, w_flat, E, k, mesh, baxes, maxes)
 
+
+def _dropless(p, x, local, w_flat, E: int, k: int):
+    """Every pair routed to a held expert, none dropped: the pairs sorted by
+    expert, one grouped product per projection, a scatter-add back to the
+    tokens in float32.  A token holds at most min(k, E) held pairs, so the
+    first T·min(k, E) sorted pairs hold them all."""
+    B, S, D = x.shape
+    T = B * S
+    flat = local.reshape(T * k)
+    key = jnp.where((flat >= 0) & (flat < E), flat, E)  # not held: sorted last
+    order = jnp.argsort(key, stable=True)[: T * min(k, E)]
+    tok = order // k
+    sizes = jnp.zeros((E,), jnp.int32).at[key].add(1, mode="drop")
+    xs = x.reshape(T, D)[tok]  # rows past the held pairs come out of each product as 0
+    h = jax.nn.silu(project(xs, p["w_gate"], group_sizes=sizes))
+    h = h * project(xs, p["w_up"], group_sizes=sizes)
+    ys = project(h, p["w_down"], group_sizes=sizes).astype(jnp.float32)
+    gate = w_flat.reshape(T * k)[order].astype(jnp.float32)
+    y = jnp.zeros((T, D), jnp.float32).at[tok].add(ys * gate[:, None])
+    return y.reshape(B, S, D).astype(x.dtype)
+
+
+def _sharded_capacity(p, x, local, w_flat, E: int, k: int, mesh, baxes, maxes):
+    """Expert-parallel over the ``model`` mesh axis with a static capacity."""
+    B, S, D = x.shape
+    C = _capacity(S, E, k)
     n_model = 1
     if maxes:
         for a in maxes:
@@ -190,13 +270,12 @@ def apply_moe(p, x, cfg: ModelConfig):
     # expert weights (or Adapted ones: w, a and b all lead with the expert
     # dim) enter sharded over (experts->model); other dims gathered
     wspec = P(maxes if maxes else None)
-    y = jax.shard_map(
+    return jax.shard_map(
         sharded_moe, mesh=mesh,
         in_specs=(P(bspec), P(bspec), P(bspec), wspec, wspec, wspec),
         out_specs=P(bspec),
         check_vma=False,
-    )(x, top_e, w_flat, p["w_gate"], p["w_up"], p["w_down"])
-    return y, {"moe_aux_loss": aux_loss}
+    )(x, local, w_flat, p["w_gate"], p["w_up"], p["w_down"])
 
 
 def _expert_ffn(p, xe):

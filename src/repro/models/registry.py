@@ -73,7 +73,7 @@ def _mlp_params(cfg: ModelConfig) -> int:
 
 def _moe_params(cfg: ModelConfig) -> int:
     D, F, E = cfg.d_model, cfg.d_ff, cfg.num_experts
-    return D * E + 3 * E * D * F
+    return D * cfg.num_router_experts + 3 * E * D * F + 3 * D * cfg.shared_d_ff
 
 
 def _mamba_params(cfg: ModelConfig) -> int:
@@ -105,7 +105,8 @@ def _sublayer_params(cfg: ModelConfig, ch: str, cross: bool = False) -> int:
         n += _moe_params(cfg) if cfg.num_experts else _mlp_params(cfg)
         return n
     if ch == "M":
-        return norm + _mamba_params(cfg)
+        ffn = norm + (_moe_params(cfg) if cfg.num_experts else _mlp_params(cfg))
+        return norm + _mamba_params(cfg) + (ffn if cfg.ssm_ffn else 0)
     if ch == "R":
         return norm + _rglru_params(cfg) + norm + _mlp_params(cfg)
     raise ValueError(ch)
@@ -138,6 +139,7 @@ def active_param_count(cfg: ModelConfig) -> int:
     """MoE: params touched per token (for MODEL_FLOPS = 6·N_active·D)."""
     if not cfg.num_experts:
         return count_params(cfg)
-    act = cfg.replace(num_experts=cfg.num_experts_per_tok)
+    act = cfg.replace(num_experts=cfg.num_experts_per_tok,
+                      router_experts=cfg.num_router_experts)
     # router counted fully; experts scaled to top-k
     return count_params(act)

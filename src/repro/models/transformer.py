@@ -70,12 +70,12 @@ def init_sublayer(key, cfg: ModelConfig, ch: str, abstract=False, cross_attn=Fal
         if cfg.use_post_norm:
             p["post_norm1"] = L.init_norm(ks[4], cfg, cfg.d_model, abstract=abstract)
             p["post_norm2"] = L.init_norm(ks[4], cfg, cfg.d_model, abstract=abstract)
-        if cfg.num_experts:
-            p["moe"] = MOE.init_moe(ks[5], cfg, abstract=abstract)
-        else:
-            p["mlp"] = L.init_mlp(ks[5], cfg, abstract=abstract)
+        p.update(_init_ffn(ks[5], cfg, abstract))
     elif ch == "M":
         p["mamba"] = M2.init_mamba(ks[1], cfg, abstract=abstract)
+        if cfg.ssm_ffn:
+            p["norm2"] = L.init_norm(ks[2], cfg, cfg.d_model, abstract=abstract)
+            p.update(_init_ffn(ks[5], cfg, abstract))
     elif ch == "R":
         p["rglru"] = RG.init_rglru_block(ks[1], cfg, abstract=abstract)
         p["norm2"] = L.init_norm(ks[2], cfg, cfg.d_model, abstract=abstract)
@@ -83,6 +83,26 @@ def init_sublayer(key, cfg: ModelConfig, ch: str, abstract=False, cross_attn=Fal
     else:
         raise ValueError(ch)
     return p
+
+
+def _init_ffn(key, cfg: ModelConfig, abstract: bool) -> dict:
+    if cfg.num_experts:
+        return {"moe": MOE.init_moe(key, cfg, abstract=abstract)}
+    return {"mlp": L.init_mlp(key, cfg, abstract=abstract)}
+
+
+def _ffn(p, h, cfg: ModelConfig):
+    """The layer's feed-forward block: (output, aux)."""
+    if "moe" in p:
+        return MOE.apply_moe(p["moe"], h, cfg)
+    return L.apply_mlp(p["mlp"], h, cfg), {}
+
+
+def _residual(x, y, cfg: ModelConfig):
+    """x + residual_multiplier·y."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+    return x + y
 
 
 def apply_sublayer(
@@ -121,7 +141,7 @@ def apply_sublayer(
             x = x + a + m
             new_cache = {"attn": new_attn_cache} if new_attn_cache is not None else None
             return x, new_cache, aux
-        x = x + a
+        x = _residual(x, a, cfg)
         if "xattn" in p and enc_out is not None:
             hx = L.apply_norm(p["norm_x"], x, cfg)
             # the cross-KV cache is only valid for decode (q_len == 1);
@@ -133,13 +153,10 @@ def apply_sublayer(
         else:
             new_x_cache = None
         h2 = L.apply_norm(p["norm2"], x, cfg)
-        if "moe" in p:
-            m, aux = MOE.apply_moe(p["moe"], h2, cfg)
-        else:
-            m = L.apply_mlp(p["mlp"], h2, cfg)
+        m, aux = _ffn(p, h2, cfg)
         if cfg.use_post_norm:
             m = L.apply_norm(p["post_norm2"], m, cfg)
-        x = x + m
+        x = _residual(x, m, cfg)
         c = {}
         if new_attn_cache is not None:
             c["attn"] = new_attn_cache
@@ -150,7 +167,10 @@ def apply_sublayer(
         h = L.apply_norm(p["norm1"], x, cfg)
         m_cache = cache.get("ssm") if cache else None
         y, new_m = M2.apply_mamba(p["mamba"], h, cfg, cache=m_cache)
-        x = x + y
+        x = _residual(x, y, cfg)
+        if "norm2" in p:
+            m, aux = _ffn(p, L.apply_norm(p["norm2"], x, cfg), cfg)
+            x = _residual(x, m, cfg)
         new_cache = {"ssm": new_m} if new_m is not None else None
     elif ch == "R":
         h = L.apply_norm(p["norm1"], x, cfg)
@@ -195,23 +215,53 @@ def init_group(key, cfg: ModelConfig, abstract=False, cross_attn=False):
             for i, ch in enumerate(chars)}
 
 
-def apply_group(gp, x, cfg: ModelConfig, *, chars=None, cache=None, cache_pos=None,
+def apply_group(gp, x, cfg: ModelConfig, *, chars=None, subs=None, cache=None, cache_pos=None,
                 positions=None, causal=True, enc_out=None, q_chunk=0, unroll_chunks=False):
+    """One copy of the pattern, or of its sublayers ``subs`` (indices into
+    it) alone.  Returns (x, new_cache, aux)."""
     chars = chars or group_chars(cfg)
     new_cache = {}
-    aux_total = None
-    for i, ch in enumerate(chars):
+    auxes = []
+    for i in (range(len(chars)) if subs is None else subs):
         sub_cache = cache.get(f"sub_{i}") if cache else None
         x, nc, aux = apply_sublayer(
-            gp[f"sub_{i}"], x, cfg, ch, cache=sub_cache, cache_pos=cache_pos,
+            gp[f"sub_{i}"], x, cfg, chars[i], cache=sub_cache, cache_pos=cache_pos,
             positions=positions, causal=causal, enc_out=enc_out,
             q_chunk=q_chunk, unroll_chunks=unroll_chunks,
         )
         if nc is not None:
             new_cache[f"sub_{i}"] = nc
-        if aux:
-            aux_total = aux if aux_total is None else jax.tree.map(lambda a, b: a + b, aux_total, aux)
-    return x, (new_cache or None), (aux_total or {})
+        auxes.append(aux)
+    return x, (new_cache or None), _merge_aux(auxes)
+
+
+def _merge_aux(auxes) -> dict:
+    """Layers' aux in order: load-balancing losses summed, the held experts'
+    loads stacked (layers, E_held)."""
+    auxes = [a for a in auxes if a]
+    if not auxes:
+        return {}
+    out = {"moe_aux_loss": functools.reduce(jnp.add, [a["moe_aux_loss"] for a in auxes])}
+    loads = [a["moe_loads"] for a in auxes if "moe_loads" in a]
+    if loads:
+        out["moe_loads"] = jnp.concatenate(loads, axis=0)
+    return out
+
+
+def layer_view(groups, cfg: ModelConfig, start: int, stop: int):
+    """The stacked group params (or adapters, keyed the same) of layers
+    [start, stop) of the scanned stack: ``sub_i`` keeps the groups g with
+    start <= g·p + i < stop, p the pattern's period.  A cut at a layer
+    boundary inside a group leaves the first sublayers of that group with
+    one entry more on the client's side and the rest on the server's."""
+    p = len(cfg.layer_pattern)
+    return {k: jax.tree.map(lambda a, i=int(k[4:]): a[-((i - start) // p): -((i - stop) // p)], v)
+            for k, v in groups.items()}
+
+
+def n_layers(groups) -> int:
+    """The layers a stack of groups, or a ``layer_view`` of one, holds."""
+    return sum(jax.tree.leaves(v)[0].shape[0] for v in groups.values())
 
 
 # ---------------------------------------------------------------------------
@@ -335,21 +385,51 @@ def _run_encoder(params, batch, cfg: ModelConfig):
 
 def _scan_groups(params, x, cfg: ModelConfig, *, cache=None, cache_pos=None,
                  positions=None, enc_out=None, q_chunk=0, remat=False,
-                 groups_slice=None, include_tail=True, unroll=False):
-    """Run the scanned group stack (+ tail). cache is threaded through scan."""
+                 groups_slice=None, include_tail=True, unroll=False, start=0):
+    """Run the scanned group stack (+ tail). cache is threaded through scan.
+    Without a cache ``params["groups"]`` may be a ``layer_view`` whose first
+    layer is ``start``.  Returns (x, new_cache, aux): aux's ``moe_aux_loss``
+    summed over the layers, and for expert layers ``moe_loads`` (layers,
+    E_held)."""
     gparams = params["groups"] if groups_slice is None else groups_slice
+    kw = dict(cache_pos=cache_pos, positions=positions, enc_out=enc_out, q_chunk=q_chunk)
 
     if cache is None:
+        # ``gparams`` may be a ``layer_view`` of layers [start, stop): a group
+        # entered past its start runs its last sublayers first (lead), one
+        # left before its end its first sublayers last (trail)
+        auxes = []
+        p = len(gparams)
+        stop = start + n_layers(gparams)
+        g0 = -(-start // p) * p  # the first group boundary at or after start
+        lead = [i % p for i in range(start, min(g0, stop))]
+        if lead:
+            x, _, aux = apply_group(_entry(gparams, lead, 0), x, cfg, subs=lead, **kw)
+            auxes.append(aux)
+            gparams = {k: jax.tree.map(lambda a: a[1:], v) if int(k[4:]) in lead else v
+                       for k, v in gparams.items()}
+        full = max(stop - g0, 0) // p
+        trail = [i % p for i in range(max(g0 + full * p, start), stop)]
+
         def body(carry, gp):
             h = carry
-            h, _, aux = apply_group(gp, h, cfg, cache=None, cache_pos=cache_pos,
-                                    positions=positions, enc_out=enc_out, q_chunk=q_chunk)
-            return h, aux.get("moe_aux_loss", jnp.zeros((), jnp.float32))
+            h, _, aux = apply_group(gp, h, cfg, cache=None, **kw)
+            return h, aux or {"moe_aux_loss": jnp.zeros((), jnp.float32)}
 
         if remat:
             body = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable)
-        x, aux_stack = jax.lax.scan(body, x, gparams, unroll=unroll)
-        aux_total = jnp.sum(aux_stack)
+        if full:
+            scanned = gparams if not trail else {
+                k: jax.tree.map(lambda a: a[:full], v) for k, v in gparams.items()}
+            x, aux_stack = jax.lax.scan(body, x, scanned, unroll=unroll)
+            aux = {"moe_aux_loss": jnp.sum(aux_stack["moe_aux_loss"])}
+            if "moe_loads" in aux_stack:
+                loads = aux_stack["moe_loads"]
+                aux["moe_loads"] = loads.reshape(-1, loads.shape[-1])
+            auxes.append(aux)
+        if trail:
+            x, _, aux = apply_group(_entry(gparams, trail, full), x, cfg, subs=trail, **kw)
+            auxes.append(aux)
         new_cache = None
     else:
         # Cache rides in the scan CARRY as one stacked buffer updated with
@@ -373,7 +453,7 @@ def _scan_groups(params, x, cfg: ModelConfig, *, cache=None, cache_pos=None,
 
         (x, new_group_cache), aux_stack = jax.lax.scan(
             body, (x, cache["groups"]), (gparams, jnp.arange(ng)), unroll=unroll)
-        aux_total = jnp.sum(aux_stack)
+        auxes = [{"moe_aux_loss": jnp.sum(aux_stack)}]
         new_cache = {"groups": new_group_cache}
     # tail layers (unscanned)
     tchars = tail_chars(cfg) if include_tail else ""
@@ -384,9 +464,13 @@ def _scan_groups(params, x, cfg: ModelConfig, *, cache=None, cache_pos=None,
                                     enc_out=enc_out, q_chunk=q_chunk)
         if cache is not None:
             new_cache[f"tail_{i}"] = nc
-        if aux:
-            aux_total = aux_total + aux.get("moe_aux_loss", 0.0)
-    return x, new_cache, aux_total
+        auxes.append(aux)
+    return x, new_cache, _merge_aux(auxes) or {"moe_aux_loss": jnp.zeros((), jnp.float32)}
+
+
+def _entry(groups, subs, j: int) -> dict:
+    """Entry ``j`` of the stacks of sublayers ``subs``: one (partial) group."""
+    return {f"sub_{i}": jax.tree.map(lambda a: a[j], groups[f"sub_{i}"]) for i in subs}
 
 
 def forward(params, batch, cfg: ModelConfig, *, kind: str = "train",
@@ -400,7 +484,7 @@ def forward(params, batch, cfg: ModelConfig, *, kind: str = "train",
                              q_chunk=q_chunk, remat=remat, unroll=unroll)
     x = L.apply_norm(params["final_norm"], x, cfg)
     logits = L.lm_logits(params["embed"], x, cfg)
-    return logits, aux
+    return logits, aux["moe_aux_loss"]
 
 
 def hidden_states(params, batch, cfg: ModelConfig, *, q_chunk: int = 0,
@@ -412,7 +496,7 @@ def hidden_states(params, batch, cfg: ModelConfig, *, q_chunk: int = 0,
         q_chunk = 2048
     x, _, aux = _scan_groups(params, x, cfg, positions=positions, enc_out=enc_out,
                              q_chunk=q_chunk, remat=remat, unroll=unroll)
-    return L.apply_norm(params["final_norm"], x, cfg), aux
+    return L.apply_norm(params["final_norm"], x, cfg), aux["moe_aux_loss"]
 
 
 def loss_fn(params, batch, cfg: ModelConfig, *, remat: bool = False, aux_weight=0.01,

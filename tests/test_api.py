@@ -214,7 +214,7 @@ def test_split_reports_codec_uplink_bits(run_cfg, batches):
     cfg = run_cfg.model
     params, axes = T.init_params(cfg, key=jax.random.PRNGKey(0))
     lora, _ = lora_lib.init_lora(params, axes, cfg, key=jax.random.PRNGKey(1))
-    lc, ls = lora_lib.split_client_server(lora, 1)
+    lc, ls = lora_lib.split_client_server(lora, 1, cfg)
     batch = jax.tree.map(lambda x: x[0], batches)
     _, _, _, dense = split.split_value_and_grad(params, lc, ls, batch, cfg, 1)
     _, _, _, comp = split.split_value_and_grad(params, lc, ls, batch, cfg, 1,

@@ -84,7 +84,7 @@ def test_pipelined_split_grads_exact():
     params, axes = T.init_params(cfg, key=jax.random.PRNGKey(0))
     lora, _ = lora_lib.init_lora(params, axes, cfg, key=jax.random.PRNGKey(1))
     lora = jax.tree.map(lambda x: x + 0.01, lora)
-    lc, ls = lora_lib.split_client_server(lora, 1)
+    lc, ls = lora_lib.split_client_server(lora, 1, cfg)
     B, S = 4, 16
     kt, kl = jax.random.split(jax.random.PRNGKey(2))
     batch = {"tokens": jax.random.randint(kt, (B, S), 0, cfg.vocab_size),

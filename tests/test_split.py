@@ -13,7 +13,7 @@ from repro.models import layers as L
 from repro.models import transformer as T
 
 FAMILIES = ["fedsllm-100m", "olmoe-1b-7b", "mamba2-130m", "recurrentgemma-9b",
-            "whisper-base"]
+            "whisper-base", "granite-4.0-h-small"]
 
 
 def setup(arch, cut=1):
@@ -24,7 +24,7 @@ def setup(arch, cut=1):
     lora_full = jax.tree.map(
         lambda x: x + 0.01 * jax.random.normal(jax.random.PRNGKey(2), x.shape, x.dtype),
         lora_full)
-    lc, ls = lora_lib.split_client_server(lora_full, cut)
+    lc, ls = lora_lib.split_client_server(lora_full, cut, cfg)
     B, S = 2, 16
     kt, kl = jax.random.split(jax.random.PRNGKey(3))
     batch = {"tokens": jax.random.randint(kt, (B, S), 0, cfg.vocab_size),
@@ -56,7 +56,7 @@ def test_split_equals_monolithic(arch):
 def test_split_join_roundtrip():
     cfg, params, lc, ls, batch = setup("fedsllm-100m", cut=1)
     joined = lora_lib.join_client_server(lc, ls)
-    lc2, ls2 = lora_lib.split_client_server(joined, 1)
+    lc2, ls2 = lora_lib.split_client_server(joined, 1, cfg)
     for a, b in zip(jax.tree.leaves(lc), jax.tree.leaves(lc2)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     for a, b in zip(jax.tree.leaves(ls), jax.tree.leaves(ls2)):
@@ -77,18 +77,20 @@ def test_unmerged_adapters_match_merged_weights(arch):
     fed ``lora.merge``d weights and no adapters they give the same loss and
     adapter gradients."""
     cfg, params, lc, ls, batch = setup(arch)
-    parts = split.slice_base(params, 1)
+    parts = split.slice_base(params, 1, cfg)
 
     def unmerged(lc, ls):
-        acts, enc_out = split.client_forward(parts.client_base, lc, batch, cfg)
-        return split.server_forward_loss(parts.server_base, ls, acts, batch, cfg,
-                                         enc_out=enc_out)
+        acts, enc_out, aux = split.client_forward(parts.client_base, lc, batch, cfg)
+        loss, _ = split.server_forward_loss(parts.server_base, ls, acts, batch, cfg,
+                                            enc_out=enc_out)
+        return loss + split.AUX_WEIGHT * aux["moe_aux_loss"]
 
     def merged(lc, ls):
-        acts, enc_out = split.client_forward(
+        acts, enc_out, aux = split.client_forward(
             lora_lib.merge(parts.client_base, lc, cfg), {}, batch, cfg)
-        return split.server_forward_loss(lora_lib.merge(parts.server_base, ls, cfg), {},
-                                         acts, batch, cfg, enc_out=enc_out)
+        loss, _ = split.server_forward_loss(lora_lib.merge(parts.server_base, ls, cfg), {},
+                                            acts, batch, cfg, enc_out=enc_out)
+        return loss + split.AUX_WEIGHT * aux["moe_aux_loss"]
 
     loss_u, grads_u = jax.value_and_grad(unmerged, argnums=(0, 1))(lc, ls)
     loss_m, grads_m = jax.value_and_grad(merged, argnums=(0, 1))(lc, ls)

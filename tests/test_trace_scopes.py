@@ -22,15 +22,26 @@ SPLIT_SCOPES = ["fedsllm.client", "transpose(jvp(fedsllm.client))", "fedsllm.ser
 MODEL_SCOPE = {"dense": "model.attention", "ssm": "model.ssd"}
 SCOPE_CASES = ([(f, s) for f in ARCH for s in SPLIT_SCOPES]
                + [(f, MODEL_SCOPE[f]) for f in ARCH])
+MOE_SCOPES = ["model.moe", "model.moe.router", "model.moe.experts", "model.moe.shared",
+              "model.ssd", "model.attention"]
+COUNTERS = ["moe_held_share", "moe_max_load"]
 ROUND_STEPS = ["fedsllm.plan", "fedsllm.batches", "fedsllm.dispatch", "fedsllm.read"]
 K, COHORT, ROUNDS = 4, 2, 2
 
 
 def _experiment(family: str) -> Experiment:
-    cfg = smoke_variant(get_arch(ARCH[family])).replace(lora=LoRAConfig(rank=4, alpha=8.0))
-    run_cfg = RunConfig(model=cfg, shape=SHAPES["train_4k"],
-                        fedsllm=FedsLLMConfig(num_clients=K))
-    return Experiment.from_config(run_cfg, allocator="EB", eta=0.5)
+    if family == "ssm-moe":
+        # granite-4.0-h-small's smoke variant at one period of three layers,
+        # holding experts 4..7 of the router's 16, cut after its first layer
+        cfg = smoke_variant(get_arch("granite-4.0-h-small")).replace(
+            layer_pattern="MGM", num_layers=3, num_experts=4, router_experts=16,
+            expert_offset=4, num_experts_per_tok=4)
+        kw = {"cut": 1}
+    else:
+        cfg, kw = smoke_variant(get_arch(ARCH[family])), {}
+    run_cfg = RunConfig(model=cfg.replace(lora=LoRAConfig(rank=4, alpha=8.0)),
+                        shape=SHAPES["train_4k"], fedsllm=FedsLLMConfig(num_clients=K))
+    return Experiment.from_config(run_cfg, allocator="EB", eta=0.5, **kw)
 
 
 def _stream(exp: Experiment) -> TokenStream:
@@ -41,15 +52,29 @@ def _stream(exp: Experiment) -> TokenStream:
 
 
 @pytest.fixture(scope="module")
-def compiled_round():
-    """family -> (Experiment, HLO text of its compiled round function)."""
+def compiled_program():
+    """family -> (Experiment, its compiled round program)."""
     cache = {}
 
     def get(family):
         if family not in cache:
             exp = _experiment(family)
             batches = client_batches(_stream(exp), 0, COHORT)
-            cache[family] = exp, exp.round_fn.lower(*exp.round_args(batches)).compile().as_text()
+            cache[family] = exp, exp.round_fn.lower(*exp.round_args(batches)).compile()
+        return cache[family]
+
+    return get
+
+
+@pytest.fixture(scope="module")
+def compiled_round(compiled_program):
+    """family -> (Experiment, HLO text of its compiled round function)."""
+    cache = {}
+
+    def get(family):
+        if family not in cache:
+            exp, compiled = compiled_program(family)
+            cache[family] = exp, compiled.as_text()
         return cache[family]
 
     return get
@@ -100,6 +125,31 @@ def test_round_never_forms_a_weight_sized_array(compiled_round, op_names, family
 def test_other_family_model_scope_absent(op_names, family):
     other = next(MODEL_SCOPE[f] for f in ARCH if f != family)
     assert not any(_in_path(other, n) for n in op_names(family))
+
+
+@pytest.mark.parametrize("scope", MOE_SCOPES)
+def test_moe_scope_in_compiled_round(op_names, scope):
+    names = op_names("ssm-moe")
+    assert any(_in_path(scope, n) for n in names), f"{scope!r} in no op_name of ssm-moe"
+
+
+@pytest.mark.parametrize("family", ["dense", "ssm", "ssm-moe"])
+def test_routing_counters_only_with_experts(compiled_program, family):
+    """The round returns the two routing counters for a model with experts
+    alone, so the dense and SSM rounds read nothing more on the host."""
+    metrics = compiled_program(family)[1].out_info[1]
+    assert all((c in metrics) == (family == "ssm-moe") for c in COUNTERS), sorted(metrics)
+
+
+@pytest.mark.parametrize("family", list(ARCH))
+def test_round_program_returns_no_copy_of_the_base(compiled_program, family):
+    """The compiled round's output (adapters, round counter, metrics) is
+    smaller than its arguments by at least the frozen base's bytes: the
+    base goes in and is not copied out."""
+    exp, compiled = compiled_program(family)
+    mem = compiled.memory_analysis()
+    base = sum(a.nbytes for a in jax.tree.leaves(exp.state.base))
+    assert mem.argument_size_in_bytes - mem.output_size_in_bytes >= base
 
 
 def _host_spans(trace_dir: str) -> list:
