@@ -9,7 +9,10 @@ One *global round* (index n):
      (eq. 9):   h ← h − δ·∇G_k(h),
      ∇G_k(h) = ∇F_k(Δw+h) − ∇F_k(Δw) + ξ·∇F(Δw),
      where each ∇F_k evaluation is a *split* forward/backward (client fwd →
-     smashed acts → server fwd/bwd → dA_k → client bwd),
+     smashed acts → server fwd/bwd → dA_k → client bwd).  The first iterate
+     is taken from ḡ: at h = 0 the two ∇F_k terms cancel, ∇G_k(0) = ξ·ḡ, so
+     a client runs I_loc split passes a round (the round-start one and
+     I_loc − 1 local ones), not I_loc + 1,
   4. fed server + main server aggregate:  Δw ← Δw + (1/K)·Σ_k h_k
      (optionally masked for stragglers / dropped clients).
 
@@ -59,6 +62,12 @@ def local_iteration_count(fcfg: FedsLLMConfig, eta: float) -> int:
     # Lemma 2 lives in delay_model.local_iters (the allocator prices the
     # same count); this is the ⌈·⌉-with-floor the training scan uses
     return max(1, int(math.ceil(dm.local_iters(fcfg, eta))))
+
+
+def split_passes(fcfg: FedsLLMConfig, eta: float) -> int:
+    """Split passes one client runs a round: the round-start pass and I_loc − 1
+    local steps, the first step being taken from ḡ (step 3 above)."""
+    return local_iteration_count(fcfg, eta)
 
 
 def global_round_count(fcfg: FedsLLMConfig, eta: float) -> int:
@@ -138,9 +147,11 @@ def build_round_fn(cfg: ModelConfig, fcfg: FedsLLMConfig, cut: int, eta: float,
                                                         compressor=compressor)
         return loss, (dc, ds), info.get("moe_loads")
 
-    def one_client_round(base, lc0, ls0, gk0, gbar, batch, ctrl=None,
+    def one_client_round(base, lc0, ls0, gk0, gbar, batch, loss0, ctrl=None,
                          ctrl_bar=None):
-        """Local update on problem (4) for one client → (h_c, h_s, loss).
+        """Local update on problem (4) for one client → (h_c, h_s, loss), the
+        loss at the last step's iterate (``loss0``, the round-start loss,
+        when I_loc is 1).
 
         The step rule is the selected local algorithm's: plain GD (eq. 9),
         FedProx's proximal pull, or SCAFFOLD's variate-corrected step
@@ -148,26 +159,29 @@ def build_round_fn(cfg: ModelConfig, fcfg: FedsLLMConfig, cut: int, eta: float,
         population mean — None for stateless algorithms).
         """
 
-        def grad_G(h):
+        def grad_G(dF):
+            # ∇G = ∇F_k(Δw+h) − ∇F_k(Δw) + ξ∇F(Δw), from a pass's ∇F_k(Δw+h)
+            return jax.tree.map(lambda a, b, c: a - b + xi * c, dF, gk0, gbar)
+
+        def step(h, dF):
+            g = algo.correct(grad_G(dF), h, ctrl, ctrl_bar)
+            return jax.tree.map(lambda x, gx: x - delta * gx, h, g)
+
+        def body(h, _):
             hc, hs = h
             lc = jax.tree.map(jnp.add, lc0, hc)
             ls = jax.tree.map(jnp.add, ls0, hs)
-            loss, (dc, ds), _ = client_grads(base, lc, ls, batch)
-            # ∇G = ∇F_k(Δw+h) − ∇F_k(Δw) + ξ∇F(Δw)
-            gc = jax.tree.map(lambda a, b, c: a - b + xi * c, dc, gk0[0], gbar[0])
-            gs = jax.tree.map(lambda a, b, c: a - b + xi * c, ds, gk0[1], gbar[1])
-            return loss, (gc, gs)
+            loss, dF, _ = client_grads(base, lc, ls, batch)
+            return step(h, dF), loss
 
+        # step 0 runs no split pass: the round-start pass is the pass at h = 0,
+        # so ∇G_k(0) = ξ·ḡ.  Taken through grad_G, as the scan's steps are:
+        # δ·(ξ·ḡ) alone would let XLA fold δ·ξ into one constant for gd but
+        # not past SCAFFOLD's correction, and zero variates would then part
+        # from gd by a rounding
         h0 = (jax.tree.map(jnp.zeros_like, lc0), jax.tree.map(jnp.zeros_like, ls0))
-
-        def body(h, _):
-            loss, g = grad_G(h)
-            g = algo.correct(g, h, ctrl, ctrl_bar)
-            h = jax.tree.map(lambda x, gx: x - delta * gx, h, g)
-            return h, loss
-
-        h, losses = jax.lax.scan(body, h0, None, length=I_loc)
-        return h[0], h[1], losses[-1]
+        h, losses = jax.lax.scan(body, step(h0, gk0), None, length=I_loc - 1)
+        return h[0], h[1], (losses[-1] if I_loc > 1 else loss0)
 
     def _round(state: FedsLLMState, batches, mask, key, weights, assign,
                update_scale, algo_state, algo_ids):
@@ -200,10 +214,10 @@ def build_round_fn(cfg: ModelConfig, fcfg: FedsLLMConfig, cut: int, eta: float,
                    else algo_ids)
             ctrl = jax.tree.map(lambda x: x[ids], algo_state)
             h_c, h_s, last_loss = jax.vmap(
-                lambda gk_c, gk_s, b, ck: one_client_round(
+                lambda gk_c, gk_s, b, l0, ck: one_client_round(
                     state.base, state.lora_c, state.lora_s, (gk_c, gk_s),
-                    gbar, b, ctrl=ck, ctrl_bar=ctrl_bar)
-            )(g0[0], g0[1], batches, ctrl)
+                    gbar, b, l0, ctrl=ck, ctrl_bar=ctrl_bar)
+            )(g0[0], g0[1], batches, loss0, ctrl)
             # variates advance on the RAW deviations (pre-DP); stragglers
             # keep theirs (the algo masks), then scatter back to the
             # population rows
@@ -214,9 +228,9 @@ def build_round_fn(cfg: ModelConfig, fcfg: FedsLLMConfig, cut: int, eta: float,
                 algo_state, upd)
         else:
             h_c, h_s, last_loss = jax.vmap(
-                lambda gk_c, gk_s, b: one_client_round(state.base, state.lora_c,
-                                                       state.lora_s, (gk_c, gk_s), gbar, b)
-            )(g0[0], g0[1], batches)
+                lambda gk_c, gk_s, b, l0: one_client_round(
+                    state.base, state.lora_c, state.lora_s, (gk_c, gk_s), gbar, b, l0)
+            )(g0[0], g0[1], batches, loss0)
 
         # 3b. optional DP on the uploaded client updates
         if dp_clip > 0.0:
@@ -248,8 +262,7 @@ def build_round_fn(cfg: ModelConfig, fcfg: FedsLLMConfig, cut: int, eta: float,
         return new_state, metrics, new_algo_state
 
     # stateless algorithms keep the legacy signature and 2-tuple return
-    # (the Python-level branch leaves the traced computation — and for
-    # ``gd`` the jaxpr itself — bit-identical to the pre-registry engine);
+    # (the Python-level branch leaves the traced computation as it is);
     # stateful ones thread the variates through two extra value-only args
     if algo.stateful:
         def round_fn(state: FedsLLMState, batches, mask=None, key=None,
@@ -265,6 +278,7 @@ def build_round_fn(cfg: ModelConfig, fcfg: FedsLLMConfig, cut: int, eta: float,
             return new_state, metrics
 
     round_fn.local_algo = algo
+    round_fn.split_passes = split_passes(fcfg, eta)
     return round_fn
 
 

@@ -197,7 +197,8 @@ def run_campaign(exp: "Experiment", num_rounds: Optional[int] = None, *,
     on ``RoundRecord.events``.
 
     Profiling: the call is the host span ``fedsllm.campaign`` and each round
-    ``fedsllm.round`` (arguments ``round``, ``cohort``, ``I_loc``), holding
+    ``fedsllm.round`` (arguments ``round``, ``cohort``, ``I_loc`` and
+    ``passes``, the split passes a client runs), holding
     ``fedsllm.round_state``, ``fedsllm.plan``, ``fedsllm.batches``,
     ``fedsllm.dispatch`` and ``fedsllm.read`` (``jax.profiler`` spans,
     recorded only while a profile is being taken).
@@ -338,8 +339,9 @@ def run_campaign(exp: "Experiment", num_rounds: Optional[int] = None, *,
         realloc_search=search)
     records: list[RoundRecord] = []
     for r in range(start, target):
-        # host spans on the profiler's clock: the round (its index, cohort
-        # and I_loc as arguments) and, inside it, each step's host time
+        # host spans on the profiler's clock: the round (its index, cohort,
+        # I_loc and split passes as arguments) and, inside it, each step's
+        # host time
         with jax.profiler.TraceAnnotation("fedsllm.round", round=r) as round_span:
             # (a) per-round scenario: channel evolution + re-attachment +
             # allocation + timing (``events.round_state`` — under
@@ -378,7 +380,8 @@ def run_campaign(exp: "Experiment", num_rounds: Optional[int] = None, *,
                 mask = None if mask_np is None else jnp.asarray(mask_np)
                 round_time = plan.round_time
             round_span.set_metadata(
-                cohort=len(ids), I_loc=fedsllm.local_iteration_count(fcfg, exp.eta))
+                cohort=len(ids), I_loc=fedsllm.local_iteration_count(fcfg, exp.eta),
+                passes=fedsllm.split_passes(fcfg, exp.eta))
 
             # (d) train the round through the ONE jitted round function
             with jax.profiler.TraceAnnotation("fedsllm.batches"):

@@ -15,7 +15,8 @@ from repro.api import (Experiment, get_local_algo, get_workload, local_algos,
 from repro.config import (FedsLLMConfig, LoRAConfig, RunConfig, SHAPES,
                           get_arch, smoke_variant)
 from repro.core import delay_model as dm
-from repro.core import fedsllm
+from repro.core import federated, fedsllm, split
+from repro.core import lora as lora_lib
 from repro.data.tokens import TokenStream
 from repro.fl.local_algos import FedProxLocal, GDLocal, ScaffoldLocal
 from repro.fl.workloads import (DirichletDomainWorkload, IIDWorkload,
@@ -273,6 +274,161 @@ def test_scaffold_checkpoint_resume_bit_identical(run_cfg, stream, tmp_path):
     with pytest.raises(ValueError, match="different campaign"):
         _fresh(run_cfg, local_algo=FedProxLocal(mu=0.0)).run(
             num_rounds=4, checkpoint_dir=ck, resume=True, **kw)
+
+
+# ---------------------------------------------------------------------------
+# The round's first local step, taken from ḡ (no split pass at h = 0)
+# ---------------------------------------------------------------------------
+
+CUT = 1
+
+
+def _eta_for(fcfg, I_loc):
+    """A training η whose Lemma 2 count is ``I_loc``."""
+    eta = 2.0 ** (-(I_loc - 0.5) / dm.lemma_v(fcfg))
+    assert fedsllm.local_iteration_count(fcfg, eta) == I_loc
+    return eta
+
+
+def _unpeeled_round(cfg, fcfg, eta, algo, compressor=None):
+    """The round as it ran before its first local step was peeled: the
+    round-start pass, then I_loc local steps from h = 0, each a split pass
+    (the first recomputing the round-start pass).  Default aggregation, the
+    first C population rows as the cohort."""
+    I_loc = fedsllm.local_iteration_count(fcfg, eta)
+    xi, delta = fcfg.xi, fcfg.delta
+    add = lambda a, b: jax.tree.map(jnp.add, a, b)  # noqa: E731
+
+    def vg(base, lc, ls, batch):
+        loss, dc, ds, _ = split.split_value_and_grad(base, lc, ls, batch, cfg, CUT,
+                                                     compressor=compressor)
+        return loss, (dc, ds)
+
+    def round_fn(state, batches, mask, algo_state):
+        C = jax.tree.leaves(batches)[0].shape[0]
+        loss0, g0 = jax.vmap(lambda b: vg(state.base, state.lora_c, state.lora_s, b))(
+            batches)
+        gbar = federated.fedavg(g0, mask=mask)
+        ctrl = ctrl_bar = None
+        if algo.stateful:
+            ctrl_bar = jax.tree.map(lambda x: jnp.mean(x, axis=0), algo_state)
+            ctrl = jax.tree.map(lambda x: x[:C], algo_state)
+
+        def client(gk, batch, ck):
+            def body(h, _):
+                loss, g = vg(state.base, add(state.lora_c, h[0]), add(state.lora_s, h[1]),
+                             batch)
+                g = jax.tree.map(lambda a, b, c: a - b + xi * c, g, gk, gbar)
+                g = algo.correct(g, h, ck, ctrl_bar)
+                return jax.tree.map(lambda x, gx: x - delta * gx, h, g), loss
+
+            h0 = jax.tree.map(jnp.zeros_like, (state.lora_c, state.lora_s))
+            h, losses = jax.lax.scan(body, h0, None, length=I_loc)
+            return h, losses[-1]
+
+        (h_c, h_s), last = jax.vmap(client)(g0, batches, ctrl)
+        new_state = state._replace(
+            lora_c=federated.apply_update(state.lora_c, federated.fedavg(h_c, mask=mask)),
+            lora_s=federated.apply_update(state.lora_s, federated.fedavg(h_s, mask=mask)),
+            round=state.round + 1)
+        metrics = {"loss_round_start": jnp.mean(loss0),
+                   "loss_local_final": jnp.mean(last),
+                   "h_c_norm": lora_lib.delta_norm(h_c)}
+        variates = None
+        if algo.stateful:
+            upd = algo.update_variates(ctrl, ctrl_bar, (h_c, h_s), mask, I_loc, delta)
+            variates = jax.tree.map(lambda full, u: full.at[:C].set(u), algo_state, upd)
+        return new_state, metrics, variates
+
+    return round_fn
+
+
+def _perturbed(tree, key, scale):
+    leaves, treedef = jax.tree.flatten(tree)
+    keys = jax.random.split(key, len(leaves))
+    return treedef.unflatten([x + scale * jax.random.normal(k, x.shape, x.dtype)
+                              for x, k in zip(leaves, keys)])
+
+
+def _rel_gap(new, old):
+    new, old = np.asarray(new, np.float64), np.asarray(old, np.float64)
+    return float(np.linalg.norm(new - old) / max(np.linalg.norm(old), 1e-30))
+
+
+PEEL_VARIANTS = {
+    "gd": dict(algo="gd"),
+    "fedprox": dict(algo=FedProxLocal(mu=0.1)),
+    "scaffold": dict(algo="scaffold"),
+    "int8": dict(algo="gd", compressor="int8"),
+    "mask": dict(algo="gd", mask=[1.0, 1.0, 0.0, 1.0]),
+}
+
+
+@pytest.mark.parametrize("I_loc", [1, 2, 3])
+@pytest.mark.parametrize("variant", list(PEEL_VARIANTS))
+def test_peeled_round_matches_unpeeled(run_cfg, stream, variant, I_loc):
+    """Taking the first local step from ḡ gives the round the unpeeled one
+    gave — state, metrics and variates — up to the float32 noise of the old
+    first step's ∇F_k(Δw) − ∇F_k(Δw), two separately fused passes."""
+    from repro.api.compressors import get_compressor
+
+    spec = PEEL_VARIANTS[variant]
+    cfg, fcfg = run_cfg.model, run_cfg.fedsllm
+    algo = get_local_algo(spec["algo"])
+    compressor = get_compressor(spec["compressor"]) if "compressor" in spec else None
+    eta = _eta_for(fcfg, I_loc)
+    state, _ = fedsllm.init_state(cfg, CUT, key=jax.random.PRNGKey(0))
+    # off the zero-B start, so both adapter factors get gradients
+    state = state._replace(
+        lora_c=_perturbed(state.lora_c, jax.random.PRNGKey(1), 0.05),
+        lora_s=_perturbed(state.lora_s, jax.random.PRNGKey(2), 0.05))
+    algo_state = (_perturbed(algo.init_variates((state.lora_c, state.lora_s), K),
+                             jax.random.PRNGKey(3), 0.01) if algo.stateful else None)
+    batches = _round_batches(stream, np.arange(COHORT))
+    mask = jnp.asarray(spec["mask"]) if "mask" in spec else None
+
+    peeled = fedsllm.build_round_fn(cfg, fcfg, CUT, eta, compressor=compressor,
+                                    local_algo=algo)
+    assert peeled.split_passes == I_loc
+    if algo.stateful:
+        new = jax.jit(peeled)(state, batches, mask, None, None, None, None, algo_state)
+    else:
+        new = jax.jit(peeled)(state, batches, mask) + (None,)
+    old = jax.jit(_unpeeled_round(cfg, fcfg, eta, algo, compressor))(
+        state, batches, mask, algo_state)
+
+    for tree in (lambda o: (o[0].lora_c, o[0].lora_s), lambda o: o[2]):
+        for a, b in zip(jax.tree.leaves(tree(new)), jax.tree.leaves(tree(old)), strict=True):
+            assert _rel_gap(a, b) < 1e-4
+    assert new[1].keys() == old[1].keys()
+    for k in old[1]:
+        assert _rel_gap(new[1][k], old[1][k]) < 1e-4, k
+    if I_loc == 1:
+        np.testing.assert_array_equal(np.asarray(new[1]["loss_local_final"]),
+                                      np.asarray(new[1]["loss_round_start"]))
+
+
+@pytest.mark.parametrize("I_loc", [1, 3])
+def test_round_runs_cohort_times_I_loc_split_passes(run_cfg, stream, monkeypatch, I_loc):
+    """A round runs cohort × I_loc split passes (the round-start pass gives
+    the first local step), counted as they run on the device."""
+    ran = []
+    vg = split.split_value_and_grad
+
+    def counted(*a, **kw):
+        out = vg(*a, **kw)
+        jax.debug.callback(lambda _: ran.append(1), out[0])  # one per client's pass
+        return out
+
+    monkeypatch.setattr(split, "split_value_and_grad", counted)
+    cfg, fcfg = run_cfg.model, run_cfg.fedsllm
+    round_fn = fedsllm.build_round_fn(cfg, fcfg, CUT, _eta_for(fcfg, I_loc))
+    assert round_fn.split_passes == I_loc
+    state, _ = fedsllm.init_state(cfg, CUT, key=jax.random.PRNGKey(0))
+    out = jax.jit(round_fn)(state, _round_batches(stream, np.arange(COHORT)))
+    jax.block_until_ready(out)
+    jax.effects_barrier()
+    assert len(ran) == COHORT * I_loc
 
 
 # ---------------------------------------------------------------------------

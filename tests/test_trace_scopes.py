@@ -182,6 +182,8 @@ def test_campaign_spans_on_the_profiler_clock(tmp_path):
     for s0, e0, _, stats in rounds:
         assert campaigns[0][0] <= s0 and e0 <= campaigns[0][1]
         assert stats["cohort"] == COHORT and stats["I_loc"] == I_loc
+        # the round-start pass gives the first local step
+        assert stats["passes"] == fedsllm.split_passes(exp.fcfg, exp.eta) == I_loc
         inside = [s for s in spans if s0 <= s[0] and s[1] <= e0 and s[2] in ROUND_STEPS]
         assert [s[2] for s in inside] == ROUND_STEPS
         ends = [s[1] for s in inside]
